@@ -33,7 +33,7 @@ def analyze(m=4096, k=4096, mean_len=16, irregular=True, n=128, dtype_b=4):
                  work_rs / nnz, a_traffic_rs / (nnz * 8)))
 
     # merge: chunks of T nonzeroes, broken at TM-row tiles
-    t = MS.DEFAULT_T
+    t = MS.default_t(m, a.nnz_pad)
     plan = MS.plan_merge(a, t=t)
     n_chunks = int(plan["cols"].shape[0])
     work_mg = n_chunks * t

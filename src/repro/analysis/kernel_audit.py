@@ -8,10 +8,11 @@ running it* and checks, statically:
   number of ``pallas_call`` launches (one per merge/rowsplit dispatch,
   one per group for rowgroup) and ``impl="xla"`` none; the traced output
   dtype must match the requested ``out_dtype``/promotion rule;
-* **VMEM footprint** — each launch is re-modeled block-for-block from
-  the kernel's BlockSpecs (double-buffered in/out blocks + scratch) and
-  summed against the per-backend budget, catching ``resolve_tk``/operand
-  blowups before any compile;
+* **VMEM and SMEM footprint** — each launch is re-modeled block-for-block
+  from the kernel's BlockSpecs (double-buffered in/out blocks + scratch
+  in VMEM; scalar-prefetch operands whole + double-buffered SMEM blocks
+  in SMEM) and summed against the per-backend budgets, catching
+  ``resolve_tk``/operand blowups before any compile;
 * **grid/index-map in-bounds** — every index map is evaluated over every
   point of the static grid (with the real scalar-prefetch arrays, e.g.
   the merge ``tile`` stream) and each block must land inside its operand;
@@ -44,11 +45,13 @@ from repro.kernels.introspect import KernelBlock, KernelLaunch
 
 from .diagnostics import Diagnostic
 
-#: Static on-chip memory budget per backend, bytes.  TPU cores have
-#: ~16 MiB of VMEM (see /opt guides); the audit models the TPU target —
-#: the CPU interpret substrate has no such limit but must not mask a
-#: lowering that could never fit real hardware.
+#: Static on-chip memory budgets per backend, bytes: a TPU v5e core has
+#: 16 MiB of VMEM and 1 MiB of SMEM (the v5e compiler refuses a kernel
+#: whose prefetched scalars exceed 1 MiB).  The audit models the TPU
+#: target — the CPU interpret substrate has no such limit but must not
+#: mask a lowering that could never fit real hardware.
 VMEM_BUDGET_BYTES = {"tpu": 16 * 2 ** 20}
+SMEM_BUDGET_BYTES = {"tpu": 2 ** 20}
 
 AUDIT_IMPLS = ("pallas", "xla")
 
@@ -249,6 +252,7 @@ def audit_method(name: str, *, n: int = 256, batch: int = 2,
     a = _representative()
     plan = build_plan(a, method=name)
     budget = VMEM_BUDGET_BYTES[backend]
+    smem_budget = SMEM_BUDGET_BYTES[backend]
     for var in _variants():
         if not _promotes_ok(var):
             diags.append(Diagnostic(
@@ -298,6 +302,12 @@ def audit_method(name: str, *, n: int = 256, batch: int = 2,
                             "K020", f"{where}:{model.label}",
                             f"modeled VMEM {mb} B exceeds the {backend} "
                             f"budget {budget} B"))
+                    if model.smem_bytes() > smem_budget:
+                        ok = False
+                        diags.append(Diagnostic(
+                            "K021", f"{where}:{model.label}",
+                            f"modeled SMEM {model.smem_bytes()} B exceeds "
+                            f"the {backend} budget {smem_budget} B"))
                     for viol in check_in_bounds(model):
                         ok = False
                         diags.append(Diagnostic(
@@ -314,43 +324,34 @@ def audit_method(name: str, *, n: int = 256, batch: int = 2,
     return rows, diags
 
 
-def nnz_vmem_ceiling(*, dtype: str = "float32", k: int = 29568,
-                     backend: str = "tpu") -> int:
-    """Largest ``nnz_pad`` whose whole-block values operand still fits.
+def merge_smem_bytes(n_chunks: int, t: int) -> int:
+    """Modeled SMEM of one merge launch: the four prefetched chunk streams
+    (``tile``, ``first``, ``last``, ``count``: 16 bytes a chunk) and the
+    step's three ``(1, 1, t)`` index/value blocks, double-buffered."""
+    return 16 * n_chunks + 3 * 2 * 4 * t
 
-    The merge/rowsplit kernels pin the raw values in VMEM as one
-    ``(1, NV)`` block (see ``merge_spmm_pallas``); with the ``(TK, TN)``
-    B panel and the C tile double-buffered beside it, this is the static
-    ceiling a real-TPU port must window past.
-    """
-    import jax.numpy as jnp
-    from repro.kernels.merge_spmm import TM, TN, resolve_tk
-    budget = VMEM_BUDGET_BYTES[backend]
-    isz = jnp.dtype(dtype).itemsize
-    tk, _ = resolve_tk(k, None)
-    fixed = 2 * (tk * TN * isz) + 2 * (TM * TN * isz) + TM * TN * 4
-    nv = (budget - fixed) // (2 * isz)
-    return max(int(nv - 1), 0)
+
+def merge_chunk_ceiling(*, backend: str = "tpu") -> int:
+    """Most merge chunks whose scalar streams still fit SMEM, at the
+    default chunk cap ``T_MAX``."""
+    from repro.kernels.merge_spmm import T_MAX
+    return (SMEM_BUDGET_BYTES[backend] - merge_smem_bytes(0, T_MAX)) // 16
 
 
 def scale_rows(*, k: int = 29568) -> list[str]:
     """Informational serving-scale probe lines for the report (the
     representative audit proves the invariants; this states where the
-    static VMEM model says the current lowering stops scaling)."""
-    from repro.kernels.merge_spmm import resolve_tk
+    static models say the current lowering stops scaling)."""
+    from repro.kernels.merge_spmm import T_MAX, resolve_tk
     tk, n_k = resolve_tk(k, None)
-    lines = [
+    chunks = merge_chunk_ceiling()
+    return [
         f"scale probe: k={k} resolves to tk={tk} ({n_k} K-tiles) — the "
         f"B panel stays {tk * 128 * 4 // 1024} KiB/buffer at any d_in",
+        f"scale probe: merge's prefetched chunk streams cap a launch at "
+        f"{chunks:,} chunks (~{chunks * T_MAX:,} nonzeros at T={T_MAX}) "
+        "of the 1 MiB SMEM",
     ]
-    for dt in ("float32", "bfloat16"):
-        ceil_nnz = nnz_vmem_ceiling(dtype=dt, k=k)
-        lines.append(
-            f"scale probe: whole-block values operand caps nnz_pad at "
-            f"~{ceil_nnz:,} ({dt}) before VMEM overflows — larger "
-            "patterns need the per-chunk values window noted in "
-            "merge_spmm_pallas")
-    return lines
 
 
 def audit_all(*, n: int = 256, batch: int = 2, tk: int | None = 64):

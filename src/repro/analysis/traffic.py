@@ -112,24 +112,23 @@ class TrafficRow:
 # pair, so its pallas DMA bytes sit well above the compulsory floor by
 # design — the tolerance pins today's re-streaming factor so any
 # *further* growth (an extra copy, a lost block-index elision) still
-# fires.  The XLA bwd numbers are dominated by the parser's
-# trip-count-scaled accounting of the ref merge's chunk scan (the
-# carried state is re-read every trip), hence the large pinned ratios
-# there; the 2%-slack baseline gate (T020) is the precision instrument
-# on top of this structural floor.
+# fires.  The XLA bwd numbers include the parser's trip-count-scaled
+# accounting of the ref merge's chunk scan (the carried state is re-read
+# every trip); the 2%-slack baseline gate (T020) is the precision
+# instrument on top of this structural floor.
 _TOLERANCE = {
-    ("merge", "pallas", "fwd"): 44.0,
-    ("merge", "pallas", "bwd"): 18.0,
-    ("merge", "xla", "fwd"): 5900.0,
-    ("merge", "xla", "bwd"): 5600.0,
+    ("merge", "pallas", "fwd"): 23.0,
+    ("merge", "pallas", "bwd"): 10.0,
+    ("merge", "xla", "fwd"): 40.0,
+    ("merge", "xla", "bwd"): 45.0,
     ("rowsplit", "pallas", "fwd"): 13.0,
     ("rowsplit", "pallas", "bwd"): 7.0,
     ("rowsplit", "xla", "fwd"): 41.0,
-    ("rowsplit", "xla", "bwd"): 4200.0,
-    ("rowgroup", "pallas", "fwd"): 12.0,
-    ("rowgroup", "pallas", "bwd"): 7.0,
-    ("rowgroup", "xla", "fwd"): 59.0,
-    ("rowgroup", "xla", "bwd"): 4200.0,
+    ("rowsplit", "xla", "bwd"): 45.0,
+    ("rowgroup", "pallas", "fwd"): 19.0,
+    ("rowgroup", "pallas", "bwd"): 9.0,
+    ("rowgroup", "xla", "fwd"): 60.0,
+    ("rowgroup", "xla", "bwd"): 50.0,
 }
 _DEFAULT_TOLERANCE = 6.0
 
@@ -145,20 +144,23 @@ _DEFAULT_TRANSPOSE = 0
 # (batch*m*n*4 = 98,304 here; +residual cotangent with the epilogue);
 # the XLA ref casts gathered operands to the accumulator dtype, so
 # bf16 xla variants carry real widen bytes; rowgroup's fused-epilogue
-# fwd un-groups in f32 before the output cast.
+# fwd un-groups in f32 before the output cast.  The Pallas kernels read
+# B as one-row slices of a 32-bit panel and the values as 32-bit SMEM
+# scalars, so a bf16 call widens both before the launch (rowgroup once
+# per group).
 _WIDEN_ALLOW = {
-    ("merge", "pallas", "fwd"): 0,
-    ("merge", "pallas", "bwd"): 196_608,
-    ("merge", "xla", "fwd"): 248_768,
-    ("merge", "xla", "bwd"): 1_013_568,
-    ("rowsplit", "pallas", "fwd"): 0,
-    ("rowsplit", "pallas", "bwd"): 196_608,
+    ("merge", "pallas", "fwd"): 395_544,
+    ("merge", "pallas", "bwd"): 594_480,
+    ("merge", "xla", "fwd"): 251_584,
+    ("merge", "xla", "bwd"): 1_009_856,
+    ("rowsplit", "pallas", "fwd"): 395_544,
+    ("rowsplit", "pallas", "bwd"): 594_480,
     ("rowsplit", "xla", "fwd"): 252_096,
-    ("rowsplit", "xla", "bwd"): 1_016_896,
-    ("rowgroup", "pallas", "fwd"): 98_304,
-    ("rowgroup", "pallas", "bwd"): 196_608,
+    ("rowsplit", "xla", "bwd"): 1_010_368,
+    ("rowgroup", "pallas", "fwd"): 2_471_568,
+    ("rowgroup", "pallas", "bwd"): 2_572_200,
     ("rowgroup", "xla", "fwd"): 1_283_776,
-    ("rowgroup", "xla", "bwd"): 1_999_424,
+    ("rowgroup", "xla", "bwd"): 1_992_896,
 }
 _DEFAULT_WIDEN = 0
 

@@ -44,7 +44,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs as _obs
@@ -284,11 +283,15 @@ class ShardedMeta:
 class ShardedSpmmPlan:
     """Per-shard SpmmPlans + the value/B gathers that stitch them together.
 
-    A pytree (per-shard plans and gather indices are the children; the
-    shard layout is static aux data), so it lives inside model pytrees and
-    passes through jit boundaries exactly like a single-device
-    ``SpmmPlan``.  Execute with :func:`execute_sharded` (or ``A @ B`` on a
-    sharded ``SparseMatrix``).
+    A pytree (the shard layout is static aux data), so it lives inside
+    model pytrees and passes through jit boundaries exactly like a
+    single-device ``SpmmPlan``.  A plan that runs as one SPMD program
+    flattens to its stacked leaves, placed one shard per mesh device
+    (:meth:`_stacked`): a jitted caller then receives every shard on its
+    own device once, at placement, instead of all of them on one device
+    and moved every call.  Other plans flatten to the per-shard plans and
+    gather indices.  Execute with :func:`execute_sharded` (or ``A @ B`` on
+    a sharded ``SparseMatrix``).
     """
 
     shards: tuple[SpmmPlan, ...]
@@ -306,6 +309,24 @@ class ShardedSpmmPlan:
                 residual: jax.Array | None = None) -> jax.Array:
         return execute_sharded(self, vals, b, exec, bias=bias,
                                residual=residual)
+
+    def __getattr__(self, name):
+        # A plan rebuilt from its stacked leaves (``_unflatten_sharded``)
+        # derives the per-shard views on first use only: the SPMD path
+        # never needs them, and slicing eagerly would copy every shard.
+        cache = self.__dict__.get("_stack_cache")
+        if name not in ("shards", "vals_slots", "b_rows") or cache is None:
+            raise AttributeError(name)
+        stacked_plan, slot_stack, brow_stack = cache
+        n = self.meta.n_shards
+        object.__setattr__(self, "shards", tuple(
+            jax.tree.map(lambda x, i=i: x[i], stacked_plan)
+            for i in range(n)))
+        object.__setattr__(self, "vals_slots",
+                           tuple(slot_stack[i] for i in range(n)))
+        object.__setattr__(self, "b_rows", None if brow_stack is None else
+                           tuple(brow_stack[i] for i in range(n)))
+        return self.__dict__[name]
 
     # Stacked leaves for the SPMD path, memoized per live (concrete) plan
     # object so the execute-many regime stacks once, not per call.  Traced
@@ -334,20 +355,27 @@ class ShardedSpmmPlan:
         return out
 
 
-def _unflatten_sharded(aux, children):
+def _flatten_sharded(sp):
+    if sp.meta.spmd_mesh() is not None:
+        return sp._stacked(), sp.meta
+    return (sp.shards, sp.vals_slots, sp.b_rows), sp.meta
+
+
+def _unflatten_sharded(meta, children):
     sp = object.__new__(ShardedSpmmPlan)
-    object.__setattr__(sp, "shards", children[0])
-    object.__setattr__(sp, "vals_slots", children[1])
-    object.__setattr__(sp, "b_rows", children[2])
-    object.__setattr__(sp, "meta", aux)
+    object.__setattr__(sp, "meta", meta)
+    if meta.spmd_mesh() is not None:
+        # Stacked leaves: kept as the SPMD operands, also under trace.
+        object.__setattr__(sp, "_stack_cache", tuple(children))
+    else:
+        object.__setattr__(sp, "shards", children[0])
+        object.__setattr__(sp, "vals_slots", children[1])
+        object.__setattr__(sp, "b_rows", children[2])
     return sp
 
 
 jax.tree_util.register_pytree_node(
-    ShardedSpmmPlan,
-    lambda sp: ((sp.shards, sp.vals_slots, sp.b_rows), sp.meta),
-    _unflatten_sharded,
-)
+    ShardedSpmmPlan, _flatten_sharded, _unflatten_sharded)
 
 
 def _unify_params(rs) -> tuple:
@@ -557,10 +585,10 @@ def _execute_spmd(plan, vals, b, exec, mesh):
             out = execute_plan(local, _local_vals(vals, slot_s[0]), b, exec)
             return out[None]
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P()),
-            out_specs=P(axis), check_rep=False,
+            out_specs=P(axis), check_vma=False,
         )(stacked_plan, slot_stack, vals, b)
         return _concat_rows([out[i] for i in range(meta.n_shards)],
                             meta.bounds)
@@ -571,8 +599,8 @@ def _execute_spmd(plan, vals, b, exec, mesh):
                                _local_b(b, brow_s[0]), exec)
         return jax.lax.psum(partial, axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(stacked_plan, slot_stack, brow_stack, vals, b)

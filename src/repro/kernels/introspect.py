@@ -33,6 +33,8 @@ class KernelBlock:
     index_map: Callable | None   # grid point -> block index, or None
     array_shape: tuple           # full operand shape
     kind: str                    # "in" | "out" | "scratch" | "scalar"
+    space: str = "vmem"          # on-chip home of the block: "vmem" |
+                                 # "smem" (scalar-prefetch is always smem)
 
     def nbytes(self) -> int:
         import jax.numpy as jnp
@@ -57,11 +59,17 @@ class KernelLaunch:
 
     def vmem_bytes(self) -> int:
         """Modeled VMEM residency: in/out blocks double-buffered (the
-        Mosaic DMA pipeline), scratch and scalar-prefetch counted once."""
-        total = 0
-        for b in self.blocks:
-            total += b.nbytes() * (2 if b.kind in ("in", "out") else 1)
-        return total
+        Mosaic DMA pipeline), scratch counted once."""
+        return sum(b.nbytes() * (2 if b.kind in ("in", "out") else 1)
+                   for b in self.blocks
+                   if b.space == "vmem" and b.kind != "scalar")
+
+    def smem_bytes(self) -> int:
+        """Modeled SMEM residency: scalar-prefetch operands whole, SMEM
+        in-blocks double-buffered."""
+        return sum(b.array_nbytes() if b.kind == "scalar"
+                   else 2 * b.nbytes() for b in self.blocks
+                   if b.kind == "scalar" or b.space == "smem")
 
     def hbm_bytes(self) -> int:
         """Transition-counted DMA traffic of the launch.

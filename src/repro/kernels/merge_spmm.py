@@ -2,23 +2,38 @@
 
 TPU adaptation of the paper's two-phase decomposition:
 
-* **Phase 1** (``plan_merge``, plain XLA): assign an equal number ``T`` of
-  nonzeroes per chunk, *breaking chunks at output row-tile boundaries* so
-  every chunk's rows live in exactly one ``TM``-row tile of C.  This is the
-  paper's ``PartitionSpmm`` binary search; the tile-boundary break replaces
-  the GPU carry-out machinery (CTAs that cannot synchronize must ship
-  boundary rows through global memory — Pallas grid steps execute in order
-  on a core, so a revisited output block simply stays resident in VMEM and
-  accumulates, and the fix-up kernel disappears).
+* **Phase 1** (``plan_merge_structure``, plain XLA): assign an equal
+  number ``T`` of nonzeroes per chunk, *breaking chunks at output row-tile
+  boundaries* so every chunk's rows live in exactly one ``TM``-row tile of
+  C.  This is the paper's ``PartitionSpmm`` binary search; the
+  tile-boundary break replaces the GPU carry-out machinery (CTAs that
+  cannot synchronize must ship boundary rows through global memory —
+  Pallas grid steps execute in order on a core, so a revisited output
+  block simply stays resident in VMEM and accumulates, and the fix-up
+  kernel disappears).
 
 * **Phase 2** (``_merge_kernel``): grid ``(batch, n_tiles, chunks,
-  k_tiles)``.  Each step gathers the ``T`` B rows named by the chunk's
-  column indices from a VMEM-resident ``(TK, TN)`` panel of B — the TPU
-  analogue of the paper's row-major coalesced loads (lane-contiguous row
-  slices) — multiplies by the chunk's values, and scatter-adds into the
-  ``(TM, TN)`` C tile through a one-hot ``(T, TM)`` matmul on the MXU.  The
+  k_tiles)``.  A step receives its chunk's column indices, row offsets
+  and values in SMEM, one ``(1, 1, T)`` block each, and walks the chunk
+  as scalars: each nonzero loads its B row from the VMEM-resident
+  ``(TK, TN)`` panel as a dynamic one-row slice — the TPU analogue of the
+  paper's row-major coalesced loads (a lane-contiguous 128-wide row) —
+  scales it by the value and adds it onto its row of a register-resident
+  ``(TM, TN)`` tile, which joins the VMEM accumulator once per step.  The
   chunk stream is ordered by row tile, so C tiles are revisited
   consecutively and flushed exactly once.
+
+Why scalars: Mosaic has no vector gather from VMEM (an in-kernel
+``jnp.take`` does not lower), so B rows are addressed by SMEM scalars.
+SMEM (1 MiB on a v5e) holds only the current step's blocks and the
+prefetched chunk streams (``tile``, ``first``, ``last`` and the per-chunk
+slot ``count``, 16 bytes a chunk), so no operand is ever held whole on
+chip.  SMEM holds
+32-bit scalars, so the values are laid out in slot order per call
+(``apply_vals``, one XLA gather) as float32; and a dynamic one-row slice
+lowers only on a 32-bit panel, so a 16-bit B is widened to float32 before
+the call.  Neither changes the arithmetic: the kernel casts both to the
+accumulator dtype anyway.
 
 Two grid axes beyond the paper's decomposition:
 
@@ -34,8 +49,9 @@ Two grid axes beyond the paper's decomposition:
   and the dataflow (and bit pattern) is exactly the unsplit kernel's.
 
 Latency hiding: the paper's ILP (32 independent loads per thread) becomes
-Mosaic's double-buffered DMA pipeline across grid steps plus ``T``
-independent VMEM gathers inside a step.  Occupancy (TLP) becomes grid size.
+``SLOT_UNROLL`` independent row loads per loop trip plus Mosaic's
+double-buffered DMA pipeline across grid steps.  Occupancy (TLP) becomes
+grid size.
 """
 from __future__ import annotations
 
@@ -51,10 +67,16 @@ from repro.core.csr import CSR, rows_from_row_ptr
 from repro.core.epilogue import apply_epilogue
 
 # Default tile sizes: TN = 128 lanes (the "warp width" / coalescing unit),
-# TM = 8 sublanes, T = nonzeroes per chunk (the paper's blockDim.x work unit).
+# TM = 8 sublanes.  T, the nonzeroes per chunk (the paper's blockDim.x work
+# unit), defaults to about one chunk per row tile (``default_t``), capped at
+# T_MAX: a step's three (1, 1, T) SMEM blocks, double-buffered, then stay
+# at 24 KiB of the 1 MiB SMEM.
 TN = 128
 TM = 8
-DEFAULT_T = 16
+T_MAX = 1024
+# Nonzeroes per trip of the kernels' scalar slot loops (``fold_slots``):
+# the independent row loads in flight, the paper's per-thread ILP.
+SLOT_UNROLL = 8
 # K-tile cap: the B panel streams through VMEM in (TK, TN) blocks.  At the
 # default, a float32 panel is 1024*128*4 = 512 KiB per buffer (~1 MiB double
 # buffered) — bounded regardless of d_in, where the old whole-(k, TN) panel
@@ -78,7 +100,20 @@ def resolve_tk(k: int, tk: int | None, *, sub: int = 8) -> tuple[int, int]:
     return tk, -(-k_pad // tk)
 
 
-def plan_merge_structure(a: CSR, *, t: int = DEFAULT_T, tm: int = TM):
+def default_t(m: int, nnz_pad: int, *, tm: int = TM) -> int:
+    """Default nonzeroes per chunk for an ``(m, ·)`` pattern.
+
+    The mean nonzero count of a ``tm``-row tile, rounded up to a power of
+    two in ``[8, T_MAX]``: one chunk per tile on average, so short-row
+    patterns do not pad every tile out to a long chunk and long-row
+    patterns take ``T_MAX`` nonzeroes per grid step.  Shapes only, so it
+    also resolves under trace.
+    """
+    per_tile = -(-nnz_pad // max(1, -(-m // tm)))
+    return int(min(T_MAX, max(8, 1 << max(0, per_tile - 1).bit_length())))
+
+
+def plan_merge_structure(a: CSR, *, t: int, tm: int = TM):
     """Phase 1, pattern-only: equal-nonzero chunks broken at TM-row tiles.
 
     Depends only on the sparsity pattern (``row_ptr``/``col_ind``), never on
@@ -114,33 +149,16 @@ def plan_merge_structure(a: CSR, *, t: int = DEFAULT_T, tm: int = TM):
 
     rows = rows_from_row_ptr(a.row_ptr, nnz_pad)   # (nnz,) row ids, pad→m
     tile_of_nz = jnp.minimum(rows // tm, n_tiles_m - 1)    # pad entries clamp
-    # nonzero count per row tile, and each nonzero's rank within its tile
-    # (tile_of_nz is non-decreasing: CSR order, pads at the end).
+    # first nonzero and nonzero count per row tile (tile_of_nz is
+    # non-decreasing: CSR order, pads at the end).
     tile_starts = jnp.searchsorted(
         tile_of_nz, jnp.arange(n_tiles_m, dtype=jnp.int32), side="left"
     ).astype(jnp.int32)
     tile_counts = jnp.diff(jnp.append(tile_starts, nnz_pad))
-    pos_in_tile = (jnp.arange(nnz_pad, dtype=jnp.int32)
-                   - tile_starts[tile_of_nz])
     # chunks allocated per tile: ceil(count/t), min 1 so that every C row
     # tile is visited (and zeroed) at least once; exclusive prefix sum.
     chunks_per_tile = jnp.maximum(1, -(-tile_counts // t))
     chunks_before = jnp.cumsum(chunks_per_tile) - chunks_per_tile
-    dest_chunk = chunks_before[tile_of_nz] + pos_in_tile // t
-    dest_slot = pos_in_tile % t
-
-    # Padded nonzeroes keep their formula slots (reserved via tile_counts of
-    # the last tile) but contribute value 0 / column 0.
-    valid = jnp.arange(nnz_pad) < a.nnz()
-    zeros_i = jnp.zeros((n_chunks, t), jnp.int32)
-    cols = zeros_i.at[dest_chunk, dest_slot].set(
-        jnp.where(valid, a.col_ind, 0), mode="drop")
-    slot_nz = jnp.full((n_chunks, t), nnz_pad, jnp.int32)
-    slot_nz = slot_nz.at[dest_chunk, dest_slot].set(
-        jnp.where(valid, jnp.arange(nnz_pad, dtype=jnp.int32), nnz_pad),
-        mode="drop")
-    lrow = zeros_i.at[dest_chunk, dest_slot].set(
-        jnp.where(valid, rows % tm, 0), mode="drop")
 
     # chunk -> row tile (non-decreasing); unused tail chunks point at the
     # last used tile so the revisit stream stays monotone.
@@ -150,6 +168,20 @@ def plan_merge_structure(a: CSR, *, t: int = DEFAULT_T, tm: int = TM):
     used = chunk_ids < cum[-1]
     tile_of_chunk = jnp.minimum(tile_of_chunk, n_tiles_m - 1)
     tile = jnp.where(used, tile_of_chunk, n_tiles_m - 1).astype(jnp.int32)
+
+    # Slot s of chunk c holds nonzero number (c - chunks_before[tile]) * t
+    # + s of its row tile, so each slot gathers its nonzero.  Padded
+    # nonzeroes (past ``nnz``) keep their formula slots (reserved via
+    # tile_counts of the last tile) but read as empty, like the slots past
+    # a tile's count.
+    pos = ((chunk_ids - chunks_before[tile])[:, None] * t
+           + jnp.arange(t, dtype=jnp.int32)[None, :])
+    nz = tile_starts[tile][:, None] + pos
+    live = (pos < tile_counts[tile][:, None]) & (nz < a.nnz())
+    slot_nz = jnp.where(live, nz, nnz_pad).astype(jnp.int32)
+    zero = jnp.zeros((1,), jnp.int32)      # the sentinel slot's entry
+    cols = jnp.concatenate([a.col_ind.astype(jnp.int32), zero])[slot_nz]
+    lrow = jnp.concatenate([rows % tm, zero])[slot_nz]
     first = jnp.concatenate(
         [jnp.ones((1,), jnp.int32),
          (tile[1:] != tile[:-1]).astype(jnp.int32)])
@@ -170,7 +202,7 @@ def apply_vals(structure: dict, vals: jax.Array) -> jax.Array:
     return vals_ext[structure["slot_nz"]]
 
 
-def plan_merge(a: CSR, *, t: int = DEFAULT_T, tm: int = TM):
+def plan_merge(a: CSR, *, t: int, tm: int = TM):
     """Phase 1 with values applied: the single-call (plan-per-call) form."""
     structure = plan_merge_structure(a, t=t, tm=tm)
     plan = dict(structure)
@@ -178,28 +210,88 @@ def plan_merge(a: CSR, *, t: int = DEFAULT_T, tm: int = TM):
     return plan
 
 
-def pack_vals(vals: jax.Array, nnz_pad: int, *, tn: int = TN) -> jax.Array:
-    """Lay the raw values out as one whole-block (1, NV) kernel operand.
+# --------------------------------------------------- shared kernel pieces ---
 
-    Zero-padded past the sentinel index ``nnz_pad`` (and up to a lane
-    multiple), so the in-kernel ``slot_nz`` gather keeps ``apply_vals``'s
-    contract — unused slots read a zero — without ever materializing the
-    padded per-slot layout in HBM.
+
+def fold_slots(n, body, carry, *, unroll: int = SLOT_UNROLL):
+    """``carry = body(s, carry)`` for slots ``s`` in ``[0, n)``.
+
+    ``unroll`` slots per loop trip (Mosaic lowers only fully unrolled or
+    plain ``fori_loop``s, so the unroll is written out), then the
+    remainder one at a time.  ``n`` may be a traced scalar (a chunk's
+    slot count read from SMEM).
     """
-    nv = tn * (-(-(nnz_pad + 1) // tn))
-    return jnp.pad(vals, (0, nv - nnz_pad)).reshape(1, nv)
+    def trip(g, c):
+        for u in range(unroll):
+            c = body(g * unroll + u, c)
+        return c
+
+    full = n // unroll
+    carry = jax.lax.fori_loop(0, full, trip, carry)
+    return jax.lax.fori_loop(full * unroll, n, body, carry)
 
 
-def _merge_kernel(tile_ref, first_ref, last_ref, cols_ref, slot_ref,
-                  lrow_ref, vals_ref, b_ref, *rest, tm: int, tk: int,
-                  n_k: int, acc_dtype, ep):
+def scaled_b_row(b_ref, col, val, kk, *, tk: int, n_k: int, acc_dtype):
+    """``val * B[col, :]`` as a ``(1, TN)`` row in ``acc_dtype``.
+
+    Read from the resident ``(TK, TN)`` panel of k-tile ``kk`` as a
+    dynamic one-row slice.  A column outside the panel contributes zero
+    on this step; the accumulator carry picks it up when its panel
+    streams in.
+    """
+    if n_k > 1:
+        local = col - kk * tk
+        inside = (local >= 0) & (local < tk)
+        val = jnp.where(inside, val, 0)
+        col = jnp.where(inside, local, 0)
+    row = b_ref[0, pl.ds(col, 1), :]
+    return val.astype(acc_dtype) * row.astype(acc_dtype)
+
+
+def split_refs(rest, ep):
+    """``(bias_ref, res_ref, o_ref, acc_ref)`` from the refs after B; the
+    epilogue operands are present exactly per ``ep``'s flags."""
     i = 0
     bias_ref = res_ref = None
     if ep is not None and ep.bias:
         bias_ref, i = rest[i], i + 1
     if ep is not None and ep.residual:
         res_ref, i = rest[i], i + 1
-    o_ref, acc_ref = rest[i], rest[i + 1]
+    return bias_ref, res_ref, rest[i], rest[i + 1]
+
+
+def flush_tile(acc_ref, o_ref, ep, bias_ref, res_ref) -> None:
+    """Write the accumulator once, with the fused epilogue: one pass over
+    C instead of a write + re-read for bias/activation/residual."""
+    r = apply_epilogue(
+        acc_ref[...], ep,
+        bias_ref[...] if bias_ref is not None else None,
+        res_ref[0] if res_ref is not None else None)
+    o_ref[0] = r.astype(o_ref.dtype)
+
+
+def smem_vals(structure: dict, vals: jax.Array) -> jax.Array:
+    """The per-call values in the structure's slot layout, as the float32
+    scalars the kernels read from SMEM."""
+    return apply_vals(structure, vals.astype(jnp.float32))
+
+
+def chunk_counts(slot_nz: jax.Array, nnz_pad: int) -> jax.Array:
+    """Per chunk, one past its last live slot: the kernel's trip count,
+    so the sentinel tail of a tile's last chunk costs no loop trips."""
+    t = slot_nz.shape[1]
+    live = slot_nz < nnz_pad
+    return jnp.max(jnp.where(live, jnp.arange(1, t + 1, dtype=jnp.int32),
+                             0), axis=1).astype(jnp.int32)
+
+
+# ------------------------------------------------------------- the kernel ---
+
+
+def _merge_kernel(tile_ref, first_ref, last_ref, count_ref, cols_ref,
+                  lrow_ref, vals_ref, b_ref, *rest, tk: int, n_k: int,
+                  acc_dtype, ep):
+    bias_ref, res_ref, o_ref, acc_ref = split_refs(rest, ep)
     c = pl.program_id(2)
     kk = pl.program_id(3)
 
@@ -207,38 +299,22 @@ def _merge_kernel(tile_ref, first_ref, last_ref, cols_ref, slot_ref,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cols = cols_ref[0]                                   # (t,)
-    lrow = lrow_ref[0]                                   # (t,)
-    # Only the columns whose B row lives in the resident (TK, TN) panel
-    # contribute on this k step; the rest are masked and picked up by the
-    # accumulator carry when their panel streams in.
-    local = cols - kk * tk
-    in_panel = (local >= 0) & (local < tk)
-    # In-kernel values gather: each slot names its flat nonzero id
-    # (sentinel nnz_pad lands in the operand's zero padding), replacing
-    # the per-call HBM materialization of the chunked values.
-    vals = jnp.take(vals_ref[0], slot_ref[0], axis=0)     # (t,)
-    vals = jnp.where(in_panel, vals, 0).astype(acc_dtype)
-    # Row-major coalesced gather of B rows (lane-contiguous slices).
-    bgat = jnp.take(b_ref[0], jnp.where(in_panel, local, 0),
-                    axis=0).astype(acc_dtype)             # (t, TN)
-    prod = vals[:, None] * bgat                           # (t, TN)
-    # Scatter-add into the TM-row tile via a one-hot matmul (MXU).
-    t = lrow.shape[0]
-    onehot = (lrow[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (t, tm), 1))
-    acc_ref[...] += jnp.dot(onehot.astype(acc_dtype).T, prod,
-                            preferred_element_type=acc_dtype)
+    # The chunk folds into a register-resident (TM, TN) tile: each scaled
+    # B row lands on its sublane through a select, the in-register form
+    # of the scatter into the C tile.
+    sub = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+
+    def slot(s, acc):
+        row = scaled_b_row(b_ref, cols_ref[0, 0, s], vals_ref[0, 0, s], kk,
+                           tk=tk, n_k=n_k, acc_dtype=acc_dtype)
+        return acc + jnp.where(sub == lrow_ref[0, 0, s], row, 0)
+
+    acc_ref[...] += fold_slots(count_ref[c], slot,
+                               jnp.zeros(acc_ref.shape, acc_dtype))
 
     @pl.when((last_ref[c] == 1) & (kk == n_k - 1))
     def _flush():
-        # Fused epilogue on the accumulator: one pass over C instead of a
-        # write + re-read for bias/activation/residual.
-        r = apply_epilogue(
-            acc_ref[...], ep,
-            bias_ref[0][:, None] if bias_ref is not None else None,
-            res_ref[0] if res_ref is not None else None)
-        o_ref[0] = r.astype(o_ref.dtype)
+        flush_tile(acc_ref, o_ref, ep, bias_ref, res_ref)
 
 
 def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
@@ -250,7 +326,7 @@ def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
     """Phase 2. ``b`` is (batch, k, n), n % tn == 0, m_pad % tm == 0.
 
     ``plan`` is the pattern structure (``plan_merge_structure``); ``vals``
-    the raw (nnz_pad,) value vector, gathered in-kernel through
+    the raw (nnz_pad,) value vector, laid out per chunk through
     ``slot_nz``.  ``epilogue`` (a ``repro.core.Epilogue``) fuses
     ``act(C + bias) * scale + residual`` into the accumulator flush —
     ``bias (m_pad,)`` and ``residual (batch, m_pad, n)`` must be present
@@ -260,72 +336,57 @@ def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
 
     Returns (batch, m_pad, n): the batch rides the leading grid axis (one
     dispatch for the whole stack) and B streams in (TK, TN) VMEM panels.
-    The raw values sit whole in VMEM as one (1, NV) block — fine on the
-    interpret/CPU substrate and at pruned-FFN sizes; a real-TPU port at
-    very large nnz would window this per chunk range.
+    Each grid step DMAs only its own chunk of indices and values into
+    SMEM; the chunk-to-tile stream, the first/last flags and the per-chunk
+    slot counts are scalar-prefetched (16 bytes a chunk of the 1 MiB
+    SMEM).
     """
+    out_dtype = b.dtype if out_dtype is None else out_dtype
+    b = b.astype(jnp.float32)       # one-row slices need a 32-bit panel
     batch, k, n = b.shape
     n_chunks, t = plan["cols"].shape
     tk, n_k = resolve_tk(k, tk)
     kpad = n_k * tk - k
     if kpad:
         b = jnp.pad(b, ((0, 0), (0, kpad), (0, 0)))
-    nnz_pad = vals.shape[0]
-    vals2 = pack_vals(vals, nnz_pad, tn=tn)
-    nv = vals2.shape[1]
+    count = chunk_counts(plan["slot_nz"], vals.shape[0])
+    per_chunk = [x.reshape(n_chunks, 1, t) for x in
+                 (plan["cols"], plan["lrow"], smem_vals(plan, vals))]
     ep = epilogue
-    out_dtype = b.dtype if out_dtype is None else out_dtype
     grid = (batch, n // tn, n_chunks, n_k)
-    in_specs = [
-        pl.BlockSpec((1, t), lambda bb, j, c, kk, tile, first, last:
-                     (c, 0)),
-        pl.BlockSpec((1, t), lambda bb, j, c, kk, tile, first, last:
-                     (c, 0)),
-        pl.BlockSpec((1, t), lambda bb, j, c, kk, tile, first, last:
-                     (c, 0)),
-        pl.BlockSpec((1, nv), lambda bb, j, c, kk, tile, first, last:
-                     (0, 0)),
-        pl.BlockSpec((1, tk, tn), lambda bb, j, c, kk, tile, first, last:
-                     (bb, kk, j)),
+    chunk_spec = pl.BlockSpec((1, 1, t), lambda bb, j, c, kk, *_: (c, 0, 0),
+                              memory_space=pltpu.SMEM)
+    in_specs = [chunk_spec] * 3 + [
+        pl.BlockSpec((1, tk, tn), lambda bb, j, c, kk, *_: (bb, kk, j)),
     ]
-    operands = [plan["cols"], plan["slot_nz"], plan["lrow"], vals2, b]
+    operands = [*per_chunk, b]
     if ep is not None and ep.bias:
         in_specs.append(pl.BlockSpec(
-            (1, tm), lambda bb, j, c, kk, tile, first, last: (tile[c], 0)))
-        operands.append(bias.reshape(m_pad // tm, tm))
+            (tm, 1), lambda bb, j, c, kk, tile, *_: (tile[c], 0)))
+        operands.append(bias.reshape(m_pad, 1))
     if ep is not None and ep.residual:
         in_specs.append(pl.BlockSpec(
-            (1, tm, tn), lambda bb, j, c, kk, tile, first, last:
-            (bb, tile[c], j)))
+            (1, tm, tn), lambda bb, j, c, kk, tile, *_: (bb, tile[c], j)))
         operands.append(residual)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, tm, tn), lambda bb, j, c, kk, tile, first, last:
-            (bb, tile[c], j)),
+            (1, tm, tn), lambda bb, j, c, kk, tile, *_: (bb, tile[c], j)),
         scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)],
     )
-    kernel = functools.partial(_merge_kernel, tm=tm, tk=tk, n_k=n_k,
+    kernel = functools.partial(_merge_kernel, tk=tk, n_k=n_k,
                                acc_dtype=acc_dtype, ep=ep)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
         interpret=interpret,
-    )(plan["tile"], plan["first"], plan["last"], *operands)
+    )(plan["tile"], plan["first"], plan["last"], count, *operands)
 
 
 # ----------------------------------------------------- static launch model ---
-
-
-def vals_launch_block(nnz_pad: int, dtype: str):
-    """The whole-block ``(1, NV)`` values operand (see ``pack_vals``)."""
-    from .introspect import KernelBlock
-    nv = TN * (-(-(nnz_pad + 1) // TN))
-    return KernelBlock("vals", (1, nv), dtype, lambda *_: (0, 0), (1, nv),
-                       "in")
 
 
 def launch_models(plan, n, batch, var, tk):
@@ -346,25 +407,26 @@ def launch_models(plan, n, batch, var, tk):
     m_pad = TM * (-(-meta.m // TM))
     ep = var.epilogue
     odt = var.out_dtype or var.b_dtype
+    chunk = lambda bb, j, c, kk: (c, 0, 0)
     blocks = [
         KernelBlock("tile", (c_n,), "int32", None, (c_n,), "scalar"),
         KernelBlock("first", (c_n,), "int32", None, (c_n,), "scalar"),
         KernelBlock("last", (c_n,), "int32", None, (c_n,), "scalar"),
-        KernelBlock("cols", (1, t), "int32",
-                    lambda bb, j, c, kk: (c, 0), (c_n, t), "in"),
-        KernelBlock("slot_nz", (1, t), "int32",
-                    lambda bb, j, c, kk: (c, 0), (c_n, t), "in"),
-        KernelBlock("lrow", (1, t), "int32",
-                    lambda bb, j, c, kk: (c, 0), (c_n, t), "in"),
-        vals_launch_block(meta.nnz_pad, var.vals_dtype),
-        KernelBlock("b", (1, tk, TN), var.b_dtype,
+        KernelBlock("count", (c_n,), "int32", None, (c_n,), "scalar"),
+        KernelBlock("cols", (1, 1, t), "int32", chunk, (c_n, 1, t), "in",
+                    "smem"),
+        KernelBlock("lrow", (1, 1, t), "int32", chunk, (c_n, 1, t), "in",
+                    "smem"),
+        KernelBlock("vals", (1, 1, t), "float32", chunk, (c_n, 1, t),
+                    "in", "smem"),
+        KernelBlock("b", (1, tk, TN), "float32",
                     lambda bb, j, c, kk: (bb, kk, j),
                     (batch, n_k * tk, n), "in"),
     ]
     if ep is not None and ep.bias:
         blocks.append(KernelBlock(
-            "bias", (1, TM), var.b_dtype,
-            lambda bb, j, c, kk: (tile[c], 0), (m_pad // TM, TM), "in"))
+            "bias", (TM, 1), var.b_dtype,
+            lambda bb, j, c, kk: (tile[c], 0), (m_pad, 1), "in"))
     if ep is not None and ep.residual:
         blocks.append(KernelBlock(
             "residual", (1, TM, TN), var.b_dtype,

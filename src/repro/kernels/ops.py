@@ -59,7 +59,7 @@ def merge_spmm(a: CSR, b: jax.Array, *, t: int | None = None,
                tk: int | None = None, interpret: bool | None = None,
                impl: str = "pallas"):
     """Merge-based SpMM: C = A @ B with equal-nonzero load balancing."""
-    t = _merge.DEFAULT_T if t is None else t
+    t = _merge.default_t(a.m, a.nnz_pad) if t is None else t
     if impl == "xla":
         return _ref.spmm_merge_ref(a, b, t=t)
     if interpret is None:
@@ -120,8 +120,8 @@ def _rowsplit_spmm_jit(a: CSR, b: jax.Array, *, l_pad: int,
         interpret = _interpret_default()
     b2 = _pad_axis(b, _rowsplit.TN, 1)
     structure = _rowsplit.plan_rowsplit_structure(a, l_pad=l_pad, tl=tl)
-    out = _rowsplit.rowsplit_spmm_pallas(structure, a.vals, b2[None], tl=tl,
-                                         tk=tk, interpret=interpret)
+    out = _rowsplit.rowsplit_spmm_pallas(structure, a.vals, b2[None], tk=tk,
+                                         interpret=interpret)
     return out[0, : a.m, : b.shape[1]]
 
 
@@ -171,8 +171,9 @@ def merge_execute(structure: dict, vals: jax.Array, b: jax.Array, *, m: int,
     ``structure`` is the pattern-only plan from
     ``merge_spmm.plan_merge_structure`` (built once per sparsity pattern by
     ``repro.core.plan`` / cached by ``repro.engine``); ``vals`` is the
-    (nnz_pad,) value vector of the call, gathered in-kernel through
-    ``slot_nz`` — no per-call padded-layout materialization in HBM.  ``b``
+    (nnz_pad,) value vector of the call, laid out per chunk through
+    ``slot_nz`` (one XLA gather per call; the kernel reads each chunk's
+    values as SMEM scalars).  ``b``
     may carry leading batch dims: (..., k, n) → (..., m, n), one kernel
     dispatch overall.
 
@@ -209,11 +210,11 @@ def merge_execute(structure: dict, vals: jax.Array, b: jax.Array, *, m: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("m", "tl", "tk", "interpret", "impl",
+                   static_argnames=("m", "tk", "interpret", "impl",
                                     "epilogue", "acc_dtype", "out_dtype"))
 def rowsplit_execute(structure: dict, vals: jax.Array, b: jax.Array, *,
-                     m: int, tl: int = _rowsplit.DEFAULT_TL,
-                     tk: int | None = None, interpret: bool | None = None,
+                     m: int, tk: int | None = None,
+                     interpret: bool | None = None,
                      impl: str = "pallas", epilogue=None, bias=None,
                      residual=None, acc_dtype=None, out_dtype=None):
     """Execute a prebuilt ELL structure: row-split SpMM with per-call values.
@@ -241,7 +242,7 @@ def rowsplit_execute(structure: dict, vals: jax.Array, b: jax.Array, *,
     m_pad = structure["cols"].shape[0]
     extra = _pad_epilogue_operands(ep, bias, residual, lead, m, n, m_pad,
                                    _rowsplit.TN)
-    out = _rowsplit.rowsplit_spmm_pallas(structure, vals, b3, tl=tl, tk=tk,
+    out = _rowsplit.rowsplit_spmm_pallas(structure, vals, b3, tk=tk,
                                          interpret=interpret, acc_dtype=adt,
                                          out_dtype=odt, **extra)
     return out[:, :m, :n].reshape(lead + (m, n))

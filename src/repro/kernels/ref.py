@@ -89,7 +89,7 @@ def _map_leading(one, *stacked):
 
 def _slot_gather(structure: dict, vals: jax.Array) -> jax.Array:
     """Per-slot values through ``slot_nz`` (sentinel → appended zero) —
-    the XLA twin of the kernels' in-kernel gather."""
+    the XLA twin of the kernels' per-call value layout."""
     vals_ext = jnp.concatenate([vals, jnp.zeros((1,), vals.dtype)])
     return vals_ext[structure["slot_nz"]]
 

@@ -178,7 +178,7 @@ def _max_row_len(a) -> int:
 
 
 def _merge_resolve(a, *, t, tl, l_pad):
-    t = _merge.DEFAULT_T if t is None else t
+    t = _merge.default_t(a.m, a.nnz_pad) if t is None else t
     tl = _rowsplit.DEFAULT_TL if tl is None else tl
     return t, tl, None, ()          # merge has no row pad
 
@@ -194,9 +194,11 @@ def _merge_execute(meta, fwd, vals, b, *, tk, interpret, impl,
 
 
 def _merge_candidates(a, wide: bool) -> Sequence[dict]:
-    cands = [dict(t=_merge.DEFAULT_T)]
+    t = _merge.default_t(a.m, a.nnz_pad)
+    cands = [dict(t=t)]
     if wide:
-        cands += [dict(t=c) for c in (8, 32) if c != _merge.DEFAULT_T]
+        cands += [dict(t=c) for c in (t // 2, 2 * t)
+                  if 8 <= c <= _merge.T_MAX]
     return cands
 
 
@@ -205,7 +207,7 @@ def _merge_inline(a, b, *, t, tl, l_pad, extra, tk, interpret, impl):
 
 
 def _rowsplit_resolve(a, *, t, tl, l_pad):
-    t = _merge.DEFAULT_T if t is None else t
+    t = _merge.default_t(a.m, a.nnz_pad) if t is None else t
     tl = _rowsplit.DEFAULT_TL if tl is None else tl
     max_len = _max_row_len(a)
     if l_pad is None:
@@ -233,7 +235,7 @@ def _rowsplit_structure(a, meta):
 def _rowsplit_execute(meta, fwd, vals, b, *, tk, interpret, impl,
                       epilogue=None, bias=None, residual=None,
                       acc_dtype=None, out_dtype=None):
-    return _ops.rowsplit_execute(fwd, vals, b, m=meta.m, tl=meta.tl, tk=tk,
+    return _ops.rowsplit_execute(fwd, vals, b, m=meta.m, tk=tk,
                                  interpret=interpret, impl=impl,
                                  epilogue=epilogue, bias=bias,
                                  residual=residual, acc_dtype=acc_dtype,

@@ -25,7 +25,7 @@ import numpy as np
 
 from . import ops as _ops
 from . import registry as _registry
-from .merge_spmm import DEFAULT_T
+from .merge_spmm import default_t
 from .rowsplit_spmm import DEFAULT_TL, TM, ell_slots
 
 # Bucketing memo keyed on the live row_ptr object (the pattern_fingerprint
@@ -143,7 +143,7 @@ def rowgroup_execute_parts(groups_meta: tuple, tl: int, fwd: dict,
         gb = None if bias_perm is None else bias_perm[start:start + m_g]
         start += m_g
         outs.append(_ops.rowsplit_execute(
-            gs, vals, b, m=m_g, tl=tl, tk=tk, interpret=interpret,
+            gs, vals, b, m=m_g, tk=tk, interpret=interpret,
             impl=impl, epilogue=group_ep, bias=gb, acc_dtype=acc_dtype,
             out_dtype=group_out))
     if not outs:
@@ -175,7 +175,7 @@ def launch_models(plan, n, batch, var, tk):
     for g, gs in enumerate(plan.fwd["groups"]):
         models.append(ell_launch(
             f"rowgroup[g{g}]", plan.meta, tuple(gs["slot_nz"].shape),
-            plan.meta.tl, n, batch, var, tk,
+            n, batch, var, tk,
             with_bias=ep is not None and ep.bias,
             with_residual=False, out_dtype=odt))
     return models
@@ -193,7 +193,7 @@ def _reject_l_pad(l_pad) -> None:
 
 
 def _resolve(a, *, t, tl, l_pad):
-    t = DEFAULT_T if t is None else t
+    t = default_t(a.m, a.nnz_pad) if t is None else t
     tl = DEFAULT_TL if tl is None else tl
     _reject_l_pad(l_pad)
     _, groups = group_rows(a.row_ptr, tl)

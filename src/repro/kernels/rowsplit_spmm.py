@@ -3,20 +3,24 @@
 TPU adaptation of the paper's warp-per-row kernel:
 
 * The GPU warp's 32 lanes reading 32 consecutive floats of a row-major B row
-  become a ``TN=128``-lane slice of B fetched from a VMEM-resident
+  become a ``TN=128``-lane row slice of B read from a VMEM-resident
   ``(TK, TN)`` panel — the dense operand streams through VMEM in K tiles
   with the accumulator carried across them (grid axis ``k_tiles``,
   innermost), so VMEM stays bounded at any ``k``; a leading ``batch`` grid
   axis executes a whole stack of dense operands per dispatch (see
   ``merge_spmm`` for the shared rationale).
 * "Equal rows per processor" becomes a grid over ``TM``-row tiles of C; each
-  row is processed in batches of ``TL`` nonzeroes, ELL-padded to the tile's
-  static bound ``L`` — the TPU manifestation of the paper's Type 2 load
-  imbalance: rows shorter than the pad waste *lanes as padding FLOPs*
-  instead of diverged threads, and the waste grows with row irregularity
-  exactly as in Fig. 4.
-* The warp ``__shfl`` broadcast of ``(col_ind, val)`` becomes a VPU
-  broadcast of the (TM, TL) index/value tiles across lanes.
+  row is ELL-padded to the static bound ``L`` (the longest row) and walked
+  in windows of up to ``L_WINDOW`` slots per grid step — the TPU
+  manifestation of the paper's Type 2 load imbalance: every row tile takes
+  the grid steps of the matrix's longest row, short rows leave them idle,
+  and the waste grows with row irregularity exactly as in Fig. 4.
+* The warp ``__shfl`` broadcast of ``(col_ind, val)`` becomes SMEM scalars:
+  each step receives its ``(TM, window)`` index/value block in SMEM and
+  walks each row's live slots (the per-row length is scalar-prefetched),
+  so ELL padding inside a window costs no loop trips.  As in
+  ``merge_spmm``, Mosaic has no in-kernel vector gather, so B rows are
+  addressed by these scalars.
 
 Phase 0 (``plan_rowsplit``, plain XLA): scatter CSR into ELL-padded
 ``(m, L)`` index/value arrays.  This is *runtime scratch within the same
@@ -34,9 +38,13 @@ from jax.experimental import pallas as pl
 
 from repro.core.csr import CSR
 
-TN = 128
-TM = 8
+from .merge_spmm import (TM, TN, apply_vals, flush_tile, fold_slots,
+                         resolve_tk, scaled_b_row, smem_vals, split_refs)
+
 DEFAULT_TL = 16
+# Most ELL slots per grid step: a (TM, L_WINDOW) int32 index block and its
+# float32 values, double-buffered, take 64 KiB of the 1 MiB SMEM.
+L_WINDOW = 512
 
 
 def ell_slots(a: CSR, rows: jax.Array, l: int, *, tm: int = TM) -> dict:
@@ -84,56 +92,66 @@ def plan_rowsplit_structure(a: CSR, *, l_pad: int, tl: int = DEFAULT_TL,
 def plan_rowsplit(a: CSR, *, l_pad: int, tl: int = DEFAULT_TL,
                   tm: int = TM):
     """Phase 0 with values applied: the single-call (plan-per-call) form."""
-    from .merge_spmm import apply_vals
     structure = plan_rowsplit_structure(a, l_pad=l_pad, tl=tl, tm=tm)
     plan = dict(structure)
     plan["vals"] = apply_vals(structure, a.vals)
     return plan
 
 
-def _rowsplit_kernel(cols_ref, slot_ref, vals_ref, b_ref, *rest,
-                     acc_dtype, n_l: int, tk: int, n_k: int, ep):
-    from repro.core.epilogue import apply_epilogue
-    i = 0
-    bias_ref = res_ref = None
-    if ep is not None and ep.bias:
-        bias_ref, i = rest[i], i + 1
-    if ep is not None and ep.residual:
-        res_ref, i = rest[i], i + 1
-    o_ref, acc_ref = rest[i], rest[i + 1]
+def slot_window(l: int) -> int:
+    """ELL slots a grid step walks: the whole row block up to
+    ``L_WINDOW``, else ``L_WINDOW`` (the block is padded to a multiple).
+    Either way the ``(TM, window)`` SMEM blocks meet Mosaic's tiling rule
+    (last dim a multiple of 128 or the whole array)."""
+    return l if l <= L_WINDOW else L_WINDOW
+
+
+def row_lengths(slot_nz: jax.Array, nnz_pad: int) -> jax.Array:
+    """Live slots per ELL row (slots fill from 0): the per-row trip
+    count, so a row's ELL padding costs no loop trips."""
+    return jnp.sum(slot_nz < nnz_pad, axis=1, dtype=jnp.int32)
+
+
+def _rowsplit_kernel(len_ref, cols_ref, vals_ref, b_ref, *rest, tm: int,
+                     tw: int, tk: int, n_k: int, acc_dtype, ep):
+    bias_ref, res_ref, o_ref, acc_ref = split_refs(rest, ep)
+    i = pl.program_id(1)
     ll = pl.program_id(3)
     kk = pl.program_id(4)
+    n_l = pl.num_programs(3)
 
     @pl.when((ll == 0) & (kk == 0))
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tm, tl = cols_ref.shape
-    cols = cols_ref[...].reshape(-1)                       # (tm*tl,)
-    # Mask to the columns whose B row is in the resident (TK, TN) panel;
-    # the rest accumulate when their panel streams in (see merge_spmm).
-    local = cols - kk * tk
-    in_panel = (local >= 0) & (local < tk)
-    # In-kernel values gather through the ELL slot ids (sentinel nnz_pad
-    # reads the operand's zero padding) — no per-call HBM materialization.
-    vals = jnp.take(vals_ref[0], slot_ref[...].reshape(-1), axis=0)
-    vals = jnp.where(in_panel, vals, 0).astype(acc_dtype)
-    bgat = jnp.take(b_ref[0], jnp.where(in_panel, local, 0),
-                    axis=0).astype(acc_dtype)              # (tm*tl, TN)
-    prod = vals[:, None] * bgat
-    acc_ref[...] += prod.reshape(tm, tl, -1).sum(axis=1)
+    # One row at a time, its window of slots folded into a (1, TN)
+    # register accumulator — the warp-per-row kernel with the warp's
+    # lane-parallel B row loads as 128-lane row slices — then selected
+    # onto its sublane of the register-resident (TM, TN) tile.
+    sub = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+
+    def row(r, acc):
+        n_r = jnp.clip(len_ref[i * tm + r] - ll * tw, 0, tw)
+
+        def slot(s, racc):
+            return racc + scaled_b_row(b_ref, cols_ref[r, s],
+                                       vals_ref[r, s], kk, tk=tk, n_k=n_k,
+                                       acc_dtype=acc_dtype)
+
+        racc = fold_slots(n_r, slot,
+                          jnp.zeros((1, acc_ref.shape[1]), acc_dtype))
+        return acc + jnp.where(sub == r, racc, 0)
+
+    acc_ref[...] += jax.lax.fori_loop(0, tm, row,
+                                      jnp.zeros(acc_ref.shape, acc_dtype))
 
     @pl.when((ll == n_l - 1) & (kk == n_k - 1))
     def _flush():
-        r = apply_epilogue(
-            acc_ref[...], ep,
-            bias_ref[0][:, None] if bias_ref is not None else None,
-            res_ref[0] if res_ref is not None else None)
-        o_ref[0] = r.astype(o_ref.dtype)
+        flush_tile(acc_ref, o_ref, ep, bias_ref, res_ref)
 
 
 def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
-                         tm: int = TM, tn: int = TN, tl: int = DEFAULT_TL,
+                         tm: int = TM, tn: int = TN,
                          tk: int | None = None, interpret: bool = False,
                          acc_dtype=jnp.float32, out_dtype=None,
                          epilogue=None, bias=None,
@@ -141,7 +159,7 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
     """``b`` is (batch, k, n) with n % tn == 0; plan arrays (m_pad, L).
 
     ``plan`` is the pattern structure (``plan_rowsplit_structure``);
-    ``vals`` the raw (nnz_pad,) values, gathered in-kernel through
+    ``vals`` the raw (nnz_pad,) values, laid out in ELL slots through
     ``slot_nz``.  ``epilogue``/``bias (m_pad,)``/``residual
     (batch, m_pad, n)`` fuse the C tail into the accumulator flush;
     ``acc_dtype``/``out_dtype`` control accumulation and output precision
@@ -149,79 +167,86 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
 
     Returns (batch, m_pad, n): batch on the leading grid axis, B streamed
     through VMEM in (TK, TN) panels (``k_tiles`` innermost, accumulator
-    carried).
+    carried).  Each step DMAs a ``(TM, window)`` block of column indices
+    and values into SMEM; the per-row lengths are scalar-prefetched.
     """
-    from .merge_spmm import pack_vals, resolve_tk
+    out_dtype = b.dtype if out_dtype is None else out_dtype
+    b = b.astype(jnp.float32)       # one-row slices need a 32-bit panel
     batch, k, n = b.shape
     m_pad, l = plan["cols"].shape
     tk, n_k = resolve_tk(k, tk)
     kpad = n_k * tk - k
     if kpad:
         b = jnp.pad(b, ((0, 0), (0, kpad), (0, 0)))
-    vals2 = pack_vals(vals, vals.shape[0], tn=tn)
-    nv = vals2.shape[1]
+    tw = slot_window(l)
+    lens = row_lengths(plan["slot_nz"], vals.shape[0])
+    slots = [plan["cols"], smem_vals(plan, vals)]
+    if l % tw:
+        slots = [jnp.pad(x, ((0, 0), (0, tw - l % tw))) for x in slots]
+    n_l = slots[0].shape[1] // tw
     ep = epilogue
-    out_dtype = b.dtype if out_dtype is None else out_dtype
-    grid = (batch, m_pad // tm, n // tn, l // tl, n_k)
-    in_specs = [
-        pl.BlockSpec((tm, tl), lambda bb, i, j, ll, kk: (i, ll)),
-        pl.BlockSpec((tm, tl), lambda bb, i, j, ll, kk: (i, ll)),
-        pl.BlockSpec((1, nv), lambda bb, i, j, ll, kk: (0, 0)),
-        pl.BlockSpec((1, tk, tn), lambda bb, i, j, ll, kk: (bb, kk, j)),
-    ]
-    operands = [plan["cols"], plan["slot_nz"], vals2, b]
+    grid = (batch, m_pad // tm, n // tn, n_l, n_k)
+    slot_spec = pl.BlockSpec((tm, tw), lambda bb, i, j, ll, kk, lens:
+                             (i, ll), memory_space=pltpu.SMEM)
+    in_specs = [slot_spec, slot_spec,
+                pl.BlockSpec((1, tk, tn), lambda bb, i, j, ll, kk, lens:
+                             (bb, kk, j))]
+    operands = [*slots, b]
     if ep is not None and ep.bias:
-        in_specs.append(pl.BlockSpec((1, tm),
-                                     lambda bb, i, j, ll, kk: (i, 0)))
-        operands.append(bias.reshape(m_pad // tm, tm))
+        in_specs.append(pl.BlockSpec((tm, 1), lambda bb, i, j, ll, kk, lens:
+                                     (i, 0)))
+        operands.append(bias.reshape(m_pad, 1))
     if ep is not None and ep.residual:
         in_specs.append(pl.BlockSpec((1, tm, tn),
-                                     lambda bb, i, j, ll, kk: (bb, i, j)))
+                                     lambda bb, i, j, ll, kk, lens:
+                                     (bb, i, j)))
         operands.append(residual)
-    kernel = functools.partial(_rowsplit_kernel, acc_dtype=acc_dtype,
-                               n_l=l // tl, tk=tk, n_k=n_k, ep=ep)
+    kernel = functools.partial(_rowsplit_kernel, tm=tm, tw=tw, tk=tk,
+                               n_k=n_k, acc_dtype=acc_dtype, ep=ep)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tm, tn),
-                               lambda bb, i, j, ll, kk: (bb, i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, tm, tn),
+                                   lambda bb, i, j, ll, kk, lens:
+                                   (bb, i, j)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)]),
         out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)],
         interpret=interpret,
-    )(*operands)
+    )(lens, *operands)
 
 
 # ----------------------------------------------------- static launch model ---
 
 
-def ell_launch(label, meta, slot_shape, tl, n, batch, var, tk, *,
+def ell_launch(label, meta, slot_shape, n, batch, var, tk, *,
                with_bias, with_residual, out_dtype):
     """One row-split-kernel launch over an (m_pad, L) ELL block — shared
     by the rowsplit method and rowgroup's per-group launches.  Mirrors
     ``rowsplit_spmm_pallas``'s grid/BlockSpec construction block-for-
     block (see ``repro.kernels.introspect``)."""
     from .introspect import KernelBlock, KernelLaunch
-    from .merge_spmm import resolve_tk, vals_launch_block
     m_pad, length = slot_shape
-    n_l = length // tl
+    tw = slot_window(length)
+    n_l = -(-length // tw)
     tk, n_k = resolve_tk(meta.k, tk)
+    slot_map = lambda bb, i, j, ll, kk: (i, ll)
     blocks = [
-        KernelBlock("cols", (TM, tl), "int32",
-                    lambda bb, i, j, ll, kk: (i, ll), (m_pad, length),
-                    "in"),
-        KernelBlock("slot_nz", (TM, tl), "int32",
-                    lambda bb, i, j, ll, kk: (i, ll), (m_pad, length),
-                    "in"),
-        vals_launch_block(meta.nnz_pad, var.vals_dtype),
-        KernelBlock("b", (1, tk, TN), var.b_dtype,
+        KernelBlock("lens", (m_pad,), "int32", None, (m_pad,), "scalar"),
+        KernelBlock("cols", (TM, tw), "int32", slot_map,
+                    (m_pad, n_l * tw), "in", "smem"),
+        KernelBlock("vals", (TM, tw), "float32", slot_map,
+                    (m_pad, n_l * tw), "in", "smem"),
+        KernelBlock("b", (1, tk, TN), "float32",
                     lambda bb, i, j, ll, kk: (bb, kk, j),
                     (batch, n_k * tk, n), "in"),
     ]
     if with_bias:
         blocks.append(KernelBlock(
-            "bias", (1, TM), var.b_dtype,
-            lambda bb, i, j, ll, kk: (i, 0), (m_pad // TM, TM), "in"))
+            "bias", (TM, 1), var.b_dtype,
+            lambda bb, i, j, ll, kk: (i, 0), (m_pad, 1), "in"))
     if with_residual:
         blocks.append(KernelBlock(
             "residual", (1, TM, TN), var.b_dtype,
@@ -245,7 +270,7 @@ def launch_models(plan, n, batch, var, tk):
     ep = var.epilogue
     return [ell_launch(
         "rowsplit", plan.meta, tuple(plan.fwd["slot_nz"].shape),
-        plan.meta.tl, n, batch, var, tk,
+        n, batch, var, tk,
         with_bias=ep is not None and ep.bias,
         with_residual=ep is not None and ep.residual,
         out_dtype=var.out_dtype or var.b_dtype)]

@@ -10,18 +10,10 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n):
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults every
-    # axis to auto sharding, which is exactly what we want anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_mesh(shape, axes):
-    """Version-gated ``jax.make_mesh`` with all-auto axis types."""
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    """``jax.make_mesh`` with all-auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

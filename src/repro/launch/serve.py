@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.configs import ARCHS, get_config, get_smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import layers as L
 from repro.models import model as M
 from repro.models import sparse as S
@@ -129,13 +130,18 @@ def make_pruned_forward(cfg):
 
 
 def serve_pruned(cfg, params, prompt, keep: float, *, microbatch: int = 0,
-                 policy=None):
+                 policy=None, blocks=None):
+    """Score ``prompt`` through the pruned-FFN forward, cold then warm.
+
+    ``blocks`` are ``prune_ffn_blocks``' output when the caller already
+    pruned (then ``keep``/``policy`` only label the report)."""
     from repro import engine
 
     check_prunable(cfg)
     t0 = time.perf_counter()
-    with obs.span("serve.plan", cat="serve", keep=keep):
-        blocks = prune_ffn_blocks(params, cfg, keep, policy=policy)
+    if blocks is None:
+        with obs.span("serve.plan", cat="serve", keep=keep):
+            blocks = prune_ffn_blocks(params, cfg, keep, policy=policy)
     t_plan = time.perf_counter() - t0
     _serve_latency.labels(phase="plan").observe(t_plan * 1e6)
     stats = engine.cache_stats()
@@ -292,6 +298,7 @@ def main(argv=None):
                     metavar="N", help="admission queue bound; submits "
                     "beyond it are shed immediately")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.prune_ffn <= 0.0:
         # These flags only shape the pruned-FFN path; silently ignoring
@@ -328,7 +335,8 @@ def main(argv=None):
         import dataclasses
 
         from repro.core import PlanPolicy, ShardSpec
-        policy = PlanPolicy(method=args.spmm_method)
+        # Serving never differentiates: no backward (transpose) plans.
+        policy = PlanPolicy(method=args.spmm_method, with_transpose=False)
         if args.mesh:
             import numpy as np
             from jax.sharding import Mesh

@@ -16,6 +16,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.data import DataConfig, make_source
 from repro.distributed import fault
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim import adamw
 from repro.runtime import steps as R
@@ -65,6 +66,7 @@ def main(argv=None):
                     help="write a JSON snapshot of the metrics registry "
                     "(step-latency histogram, plan counters) on exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs.enable()

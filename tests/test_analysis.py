@@ -422,12 +422,24 @@ def test_audit_in_bounds_catches_overrun():
 
 
 def test_audit_vmem_budget_flags_blowup():
-    from repro.analysis.kernel_audit import nnz_vmem_ceiling
-    # The documented ceiling must be consistent: one more f32 nonzero
-    # than the ceiling overflows the 16 MiB model.
-    c = nnz_vmem_ceiling(dtype="float32")
-    assert 0 < c < 16 * 2 ** 20
-    assert nnz_vmem_ceiling(dtype="bfloat16") > c
+    from repro.analysis.kernel_audit import (SMEM_BUDGET_BYTES,
+                                             VMEM_BUDGET_BYTES, Block,
+                                             LaunchModel, merge_chunk_ceiling,
+                                             merge_smem_bytes)
+    from repro.kernels.merge_spmm import T_MAX
+    # A 16 MiB block, double-buffered, overflows the 16 MiB VMEM model and
+    # counts nothing against SMEM.
+    big = Block("b", (4096, 1024), "float32", lambda i: (0, 0),
+                (4096, 1024), "in")
+    model = LaunchModel("big", grid=(1,), blocks=(big,),
+                        flush=lambda i: True, out=big)
+    assert model.vmem_bytes() > VMEM_BUDGET_BYTES["tpu"]
+    assert model.smem_bytes() == 0
+    # The documented merge ceiling is consistent: one chunk more than the
+    # ceiling overflows the 1 MiB SMEM model.
+    c = merge_chunk_ceiling()
+    assert merge_smem_bytes(c, T_MAX) <= SMEM_BUDGET_BYTES["tpu"] \
+        < merge_smem_bytes(c + 1, T_MAX)
 
 
 # --------------------------------------------------------------- repo lint ---

@@ -57,7 +57,8 @@ def test_cache_key_resolves_auto_and_defaults():
     a = _csr(2, npr=(0, 4))                  # short rows → heuristic: merge
     assert Heuristic().choose(a) == "merge"
     p1 = cache.get(a, PlanPolicy(method="auto"))
-    p2 = cache.get(a, PlanPolicy(method="merge", t=merge_spmm.DEFAULT_T))
+    p2 = cache.get(a, PlanPolicy(method="merge",
+                                 t=merge_spmm.default_t(a.m, a.nnz_pad)))
     assert p1 is p2 and cache.stats().hits == 1
 
 
