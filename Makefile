@@ -3,7 +3,7 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH),)
 
 .PHONY: ci test test-sharded smoke examples-smoke bench tune tune-smoke \
 	bench-batched-smoke bench-sharded-smoke bench-epilogue-smoke \
-	bench-obs-smoke trace-smoke serve-smoke lint analyze \
+	trace-smoke serve-smoke lint analyze \
 	traffic-baseline
 
 # examples-smoke subsumes the quickstart smoke (runs it in full), so ci
@@ -100,15 +100,6 @@ bench-epilogue-smoke:
 	REPRO_BENCH_EPILOGUE=smoke $(PY) -m benchmarks.run epilogue \
 	    > artifacts/bench_epilogue.csv
 	cat artifacts/bench_epilogue.csv
-
-# CI smoke: obs-enabled corpus bench — traced engine execution with the
-# live roofline accountant; prints obs.report() (achieved bandwidth vs
-# the measured streaming roof per method) and lands the CSV in artifacts/
-bench-obs-smoke:
-	mkdir -p artifacts
-	REPRO_BENCH_OBS=smoke $(PY) -m benchmarks.run obs \
-	    > artifacts/bench_obs.csv
-	cat artifacts/bench_obs.csv
 
 # CI smoke: traced interpret-mode serve + train — Chrome trace-event JSON
 # and metrics dumps land in artifacts/ and are schema-validated

@@ -18,7 +18,7 @@ def _mods():
     from . import (bench_batched, bench_corpus, bench_epilogue,
                    bench_fig1_imbalance, bench_fig4_aspect,
                    bench_fig5_rows, bench_fig6_heuristic,
-                   bench_fig7_density, bench_obs, bench_plan_reuse,
+                   bench_fig7_density, bench_plan_reuse,
                    bench_serving, bench_sharded, bench_table1_analysis,
                    bench_train_step, bench_moe_balance)
     return [
@@ -35,7 +35,6 @@ def _mods():
         ("sharded", bench_sharded),
         ("train", bench_train_step),
         ("corpus", bench_corpus),
-        ("obs", bench_obs),
         ("serving", bench_serving),
     ]
 

@@ -19,8 +19,9 @@ condition's bound constant — JAX counted loops start at 0).
 
 Grown out of ``repro.launch.hlo_stats`` (which remains as a re-export
 shim): this is the shared backend of the dry-run validator
-(``repro.launch.dryrun``), the roofline accountant (``repro.obs``) and
-the static bytes-moved analyzer (``repro.analysis.traffic``).
+(``repro.launch.dryrun``), the device-op scope join
+(``repro.obs.device_scopes``) and the static bytes-moved analyzer
+(``repro.analysis.traffic``).
 ``parse_compiled(..., detail=True)`` additionally reports the
 multi-computation breakdown and the fusion/op histogram so hidden
 copies and transposes are attributable to a computation.
@@ -39,8 +40,9 @@ _DTYPE_BYTES = {
 }
 
 _ARRAY_RE = re.compile(r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([\d,]*)\]")
+# A TPU layout carries tiling and memory space: ``f32[8]{0:T(1024)S(1)}``.
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\(.*?\)|[\w\[\],{}]+)\s+"
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\(.*?\)|[\w\[\],{}:()]+)\s+"
     r"([\w\-]+)\((.*)$")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{")
 _GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
@@ -253,7 +255,7 @@ def parse_compiled(fn, *args, detail: bool = False, **kwargs) -> dict:
 
     ``fn`` is jit-wrapped if it isn't already; ``*args``/``**kwargs`` are
     the abstract or concrete operands to lower for.  The convenience the
-    roofline accountant, the obs bench and the traffic analyzer use: one
+    traffic analyzer uses: one
     call from a callable to the traffic model's {flops, hbm_bytes,
     collective_*} dict.  ``detail=True`` adds the per-computation
     breakdown and the ``fusion_ops`` histogram (see :func:`parse_module`)

@@ -32,16 +32,52 @@ pattern, with ``repro.engine.get_plan``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs as _obs
 from repro.analysis import _flags as _verify_flags
+from repro.obs import trace as _trace
 
 from .csr import CSR
 from .heuristic import Heuristic
+
+# Host seconds of each plan-build step, always on: a build runs in set-up,
+# a handful of times per pattern, never on the execute path.  Steps:
+# ``resolve`` (the policy ladder, ``engine/cache.py``), ``fingerprint``
+# (pattern copied to the host and hashed), ``structure`` (the method's
+# structure builder, plus the transpose plan when asked for) and
+# ``row_ids`` (per-nonzero row ids built on the host and copied back).
+# ``method`` is the resolved method; ``fingerprint`` runs before any
+# method is known and records ``method=""``.
+# The structure builders dispatch device work asynchronously: a step's
+# time is its host time, and device work still in flight when it ends
+# counts in whichever later step first waits for it (none of the build's
+# own steps does; the caller's ``block_until_ready`` on the plan does).
+_build_step = _obs.registry.histogram(
+    "plan_build_step_seconds", "host seconds of each plan-build step",
+    labels=("method", "step"))
+
+
+@contextlib.contextmanager
+def build_step(step: str, method: str = ""):
+    """Time one plan-build step: a ``plan.step.<step>`` span (nested in
+    any open ``plan.build``) and a ``plan_build_step_seconds``
+    observation under ``method``.  A step that resolves the method sets
+    it on the yielded record (``with build_step("resolve") as st: ...;
+    st.method = r.method``)."""
+    st = types.SimpleNamespace(method=method)
+    t0 = time.perf_counter()
+    with _trace.span(f"plan.step.{step}", cat="plan"):
+        yield st
+    _build_step.labels(method=st.method, step=step).observe(
+        time.perf_counter() - t0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,32 +252,40 @@ def build_plan(a: CSR, *, method: str = "auto",
     # ``_resolved``: a ResolvedPlan the caller (the engine cache) already
     # computed for this exact request — skips re-running the ladder and
     # per-method derivation (e.g. rowgroup's host-side bucketing).
-    r = _resolved if _resolved is not None else policy.resolve(a)
+    if _resolved is None:
+        with build_step("resolve") as st:
+            r = policy.resolve(a)
+            st.method = r.method
+    else:
+        r = _resolved
     meta = PlanMeta(method=r.method, shape=a.shape, nnz_pad=a.nnz_pad,
                     t=r.t, tl=r.tl, l_pad=r.l_pad,
                     has_transpose=policy.with_transpose, extra=r.extra)
-    fwd = dict(registry.get_method(r.method).build_structure(a, meta))
+    nnz_pad = a.nnz_pad
+    with build_step("structure", r.method):
+        fwd = dict(registry.get_method(r.method).build_structure(a, meta))
+        bwd = None
+        if policy.with_transpose:
+            a_t, perm = transpose_pattern(a)
+            # The backward dB = Aᵀ @ dC always runs merge-based: equal-
+            # nonzero balancing on the CSC view, independent of the forward
+            # method.
+            bwd = dict(merge_k.plan_merge_structure(a_t, t=r.t))
+            # Backward slots index *original* vals: compose chunk slots
+            # with the transpose permutation once, at build time.
+            bwd["slot_nz"] = _compose_slots(bwd["slot_nz"], perm, nnz_pad)
 
     # Per-nonzero coordinates for the SDDMM values-cotangent (in-bounds
     # everywhere; validity carried separately).
-    rp = np.asarray(a.row_ptr)
-    nnz = int(rp[-1])
-    nnz_pad = a.nnz_pad
-    nz_rows = np.zeros(nnz_pad, np.int32)
-    nz_rows[:nnz] = np.repeat(np.arange(a.m, dtype=np.int32), np.diff(rp))
-    fwd["nz_rows"] = jnp.asarray(nz_rows)
-    fwd["nz_cols"] = a.col_ind
-    fwd["nz_valid"] = jnp.asarray(np.arange(nnz_pad) < nnz)
-
-    bwd = None
-    if policy.with_transpose:
-        a_t, perm = transpose_pattern(a)
-        # The backward dB = Aᵀ @ dC always runs merge-based: equal-nonzero
-        # balancing on the CSC view, independent of the forward method.
-        bwd = dict(merge_k.plan_merge_structure(a_t, t=r.t))
-        # Backward slots index *original* vals: compose chunk slots with the
-        # transpose permutation once, at build time.
-        bwd["slot_nz"] = _compose_slots(bwd["slot_nz"], perm, nnz_pad)
+    with build_step("row_ids", r.method):
+        rp = np.asarray(a.row_ptr)
+        nnz = int(rp[-1])
+        nz_rows = np.zeros(nnz_pad, np.int32)
+        nz_rows[:nnz] = np.repeat(np.arange(a.m, dtype=np.int32),
+                                  np.diff(rp))
+        fwd["nz_rows"] = jnp.asarray(nz_rows)
+        fwd["nz_cols"] = a.col_ind
+        fwd["nz_valid"] = jnp.asarray(np.arange(nnz_pad) < nnz)
     plan = SpmmPlan(fwd=fwd, bwd=bwd, meta=meta)
     if _verify_flags.verify_plans:
         # Opt-in debug hook (REPRO_VERIFY_PLANS=1): full host-side
@@ -264,7 +308,8 @@ def pattern_fingerprint(a: CSR) -> str:
 
     Memoized per live CSR object (identity-checked via weakref), so the
     O(nnz) device→host hash is paid once per object, not per call — a
-    serving loop that holds one CSR hits the plan cache in O(1).
+    serving loop that holds one CSR hits the plan cache in O(1).  The
+    hash itself is the ``fingerprint`` plan-build step.
     """
     import hashlib
     import weakref
@@ -274,10 +319,11 @@ def pattern_fingerprint(a: CSR) -> str:
     memo = _fingerprint_memo.get(key)
     if memo is not None and memo[0]() is a:
         return memo[1]
-    h = hashlib.sha1()
-    h.update(np.asarray(a.row_ptr).tobytes())
-    h.update(np.asarray(a.col_ind).tobytes())
-    fp = h.hexdigest()
+    with build_step("fingerprint"):
+        h = hashlib.sha1()
+        h.update(np.asarray(a.row_ptr).tobytes())
+        h.update(np.asarray(a.col_ind).tobytes())
+        fp = h.hexdigest()
     try:
         ref = weakref.ref(a, lambda _, k=key: _fingerprint_memo.pop(k, None))
     except TypeError:       # object not weakref-able: skip the memo

@@ -171,28 +171,20 @@ def _resolve_exec(where: str, m: int, vals, b, exec: ExecutionConfig,
 def _forward(meta: PlanMeta, fwd: dict, vals, b, exec: ExecutionConfig,
              bias, residual, *, vmappable: bool):
     registry = _registry()
-    if _trace._enabled:
-        # Label the kernel region in any enclosing XLA profile; the
-        # host-side span/event was already emitted by the dispatcher.
-        with jax.named_scope(f"spmm_{meta.method}_{exec.impl}"):
-            return _forward_inner(registry, meta, fwd, vals, b, exec,
-                                  bias, residual, vmappable=vmappable)
-    return _forward_inner(registry, meta, fwd, vals, b, exec, bias,
-                          residual, vmappable=vmappable)
-
-
-def _forward_inner(registry, meta, fwd, vals, b, exec, bias, residual, *,
-                   vmappable: bool):
-    if vmappable:
-        op = registry.execute_op(meta, exec.tk, exec.interpret, exec.impl,
-                                 exec.epilogue, exec.acc_dtype,
-                                 exec.out_dtype)
-        return op(fwd, vals, b, bias, residual)
-    return registry.get_method(meta.method).execute(
-        meta, fwd, vals, b, tk=exec.tk, interpret=exec.interpret,
-        impl=exec.impl, epilogue=exec.epilogue, bias=bias,
-        residual=residual, acc_dtype=exec.acc_dtype,
-        out_dtype=exec.out_dtype)
+    # Always on: a named scope is trace-time metadata, free at run time.
+    # Its parts (gather_vals, widen_b, pad, kernel, unpad) open inside the
+    # kernel wrappers; ``repro.obs.device_scopes`` reads them back.
+    with _obs.scope("spmm"):
+        if vmappable:
+            op = registry.execute_op(meta, exec.tk, exec.interpret,
+                                     exec.impl, exec.epilogue,
+                                     exec.acc_dtype, exec.out_dtype)
+            return op(fwd, vals, b, bias, residual)
+        return registry.get_method(meta.method).execute(
+            meta, fwd, vals, b, tk=exec.tk, interpret=exec.interpret,
+            impl=exec.impl, epilogue=exec.epilogue, bias=bias,
+            residual=residual, acc_dtype=exec.acc_dtype,
+            out_dtype=exec.out_dtype)
 
 
 def _int_zeros(tree):
@@ -233,51 +225,53 @@ def _execute_vjp_fwd(meta, exec, fwd, bwd, vals, b, bias, residual):
     pre = _forward(meta, fwd, vals, b, pre_exec,
                    bias if ep.bias else None, None, vmappable=True)
     tail = dataclasses.replace(ep, bias=False)
-    out = apply_epilogue(pre, tail, None,
-                         residual if ep.residual else None)
-    return out.astype(jnp.dtype(exec.out_dtype)), \
-        (fwd, bwd, vals, b, bias, residual, pre)
+    with _obs.scope("spmm"), _obs.scope("unpad"):
+        out = apply_epilogue(pre, tail, None,
+                             residual if ep.residual else None)
+        out = out.astype(jnp.dtype(exec.out_dtype))
+    return out, (fwd, bwd, vals, b, bias, residual, pre)
 
 
 def _execute_vjp_bwd(meta, exec, res, dc):
-    fwd, bwd, vals, b, bias, residual, pre = res
-    ops = _ops()
-    ep = exec.epilogue
-    acc = jnp.dtype(exec.acc_dtype) if exec.acc_dtype else jnp.float32
-    # Epilogue chain rule, peeled outside-in: out = act(C + bias) * scale
-    # + residual  ⇒  d_residual = dc;  g = act'(pre) · (dc * scale) is the
-    # cotangent of C (and of bias, row-summed).
-    d_res = dc.astype(residual.dtype) \
-        if ep is not None and ep.residual else None
-    g = dc.astype(acc)
-    if ep is not None:
-        if ep.scale is not None:
-            g = g * ep.scale
-        if ep.activation != "none":
-            _, act_vjp = jax.vjp(activation_fn(ep.activation),
-                                 pre.astype(acc))
-            g = act_vjp(g)[0]
-    d_bias = None
-    if ep is not None and ep.bias:
-        d_bias = g.sum(axis=-1)
-        if d_bias.ndim > 1:
-            # Explicit leading batch dims: the bias is shared across them.
-            d_bias = d_bias.sum(axis=tuple(range(d_bias.ndim - 1)))
-        d_bias = d_bias.astype(bias.dtype)
-    # dB = Aᵀ @ g through the transpose merge plan: the CSC view gets the
-    # same equal-nonzero balancing as the forward pass (batched like it).
-    db = ops.merge_execute_op(meta.k, exec.tk, exec.interpret, exec.impl)(
-        bwd, vals, g).astype(b.dtype)
-    # dvals = (g · Bᵀ) sampled at the pattern (gather-dot SDDMM), reduced
-    # over any explicit batch dims — the values are shared across the batch.
-    # (Under vmap the axis is implicit and JAX itself sums the cotangent
-    # for the unbatched values primal.)
-    dvals = ops.sddmm_op(exec.interpret, exec.impl)(
-        fwd["nz_rows"], fwd["nz_cols"], fwd["nz_valid"], g, b)
-    if dvals.ndim > 1:
-        dvals = dvals.sum(axis=tuple(range(dvals.ndim - 1)))
-    return (_int_zeros(fwd), _int_zeros(bwd), dvals.astype(vals.dtype), db,
-            d_bias, d_res)
+    with _obs.scope("spmm"):
+        fwd, bwd, vals, b, bias, residual, pre = res
+        ops = _ops()
+        ep = exec.epilogue
+        acc = jnp.dtype(exec.acc_dtype) if exec.acc_dtype else jnp.float32
+        # Epilogue chain rule, peeled outside-in: out = act(C + bias) * scale
+        # + residual  ⇒  d_residual = dc;  g = act'(pre) · (dc * scale) is the
+        # cotangent of C (and of bias, row-summed).
+        d_res = dc.astype(residual.dtype) \
+            if ep is not None and ep.residual else None
+        g = dc.astype(acc)
+        if ep is not None:
+            if ep.scale is not None:
+                g = g * ep.scale
+            if ep.activation != "none":
+                _, act_vjp = jax.vjp(activation_fn(ep.activation),
+                                     pre.astype(acc))
+                g = act_vjp(g)[0]
+        d_bias = None
+        if ep is not None and ep.bias:
+            d_bias = g.sum(axis=-1)
+            if d_bias.ndim > 1:
+                # Explicit leading batch dims: the bias is shared across them.
+                d_bias = d_bias.sum(axis=tuple(range(d_bias.ndim - 1)))
+            d_bias = d_bias.astype(bias.dtype)
+        # dB = Aᵀ @ g through the transpose merge plan: the CSC view gets the
+        # same equal-nonzero balancing as the forward pass (batched like it).
+        db = ops.merge_execute_op(meta.k, exec.tk, exec.interpret, exec.impl)(
+            bwd, vals, g).astype(b.dtype)
+        # dvals = (g · Bᵀ) sampled at the pattern (gather-dot SDDMM),
+        # reduced over any explicit batch dims — the values are shared
+        # across the batch.  (Under vmap the axis is implicit and JAX itself
+        # sums the cotangent for the unbatched values primal.)
+        dvals = ops.sddmm_op(exec.interpret, exec.impl)(
+            fwd["nz_rows"], fwd["nz_cols"], fwd["nz_valid"], g, b)
+        if dvals.ndim > 1:
+            dvals = dvals.sum(axis=tuple(range(dvals.ndim - 1)))
+        return (_int_zeros(fwd), _int_zeros(bwd), dvals.astype(vals.dtype), db,
+                d_bias, d_res)
 
 
 _execute_vjp.defvjp(_execute_vjp_fwd, _execute_vjp_bwd)
@@ -504,16 +498,20 @@ def spmm(a: CSR, b: jax.Array, policy: PlanPolicy | None = None,
                      impl=exec.impl, inline=True, n=int(b.shape[-1]),
                      tk=exec.tk, acc_dtype=exec.acc_dtype,
                      out_dtype=exec.out_dtype)
-    out = spec.inline(a, b, t=t_val, tl=tl_val, l_pad=l_val, extra=extra,
-                      tk=exec.tk, interpret=exec.interpret, impl=exec.impl)
-    # The inline forms predate the fused tail: apply the epilogue (and the
-    # dtype contract) post hoc — same math as the fused paths, none of the
-    # fusion, which only matters in the plan-once serving regime anyway.
-    ep = exec.epilogue
-    if ep is not None:
-        acc = jnp.dtype(exec.acc_dtype)
-        out = apply_epilogue(
-            out.astype(acc), ep,
-            bias.astype(acc)[:, None] if ep.bias else None,
-            residual if ep.residual else None)
-    return out.astype(jnp.dtype(exec.out_dtype))
+    with _obs.scope("spmm"):
+        out = spec.inline(a, b, t=t_val, tl=tl_val, l_pad=l_val,
+                          extra=extra, tk=exec.tk, interpret=exec.interpret,
+                          impl=exec.impl)
+        # The inline forms predate the fused tail: apply the epilogue (and
+        # the dtype contract) post hoc — same math as the fused paths, none
+        # of the fusion, which only matters in the plan-once serving regime
+        # anyway.
+        with _obs.scope("unpad"):
+            ep = exec.epilogue
+            if ep is not None:
+                acc = jnp.dtype(exec.acc_dtype)
+                out = apply_epilogue(
+                    out.astype(acc), ep,
+                    bias.astype(acc)[:, None] if ep.bias else None,
+                    residual if ep.residual else None)
+            return out.astype(jnp.dtype(exec.out_dtype))

@@ -25,7 +25,8 @@ from repro.analysis import _flags as _verify_flags
 from repro.core.config import (DEFAULT_TUNEDB, PlanPolicy, _UNSET,
                                _warn_deprecated)
 from repro.core.csr import CSR
-from repro.core.plan import SpmmPlan, build_plan, pattern_fingerprint
+from repro.core.plan import (SpmmPlan, build_plan, build_step,
+                             pattern_fingerprint)
 from repro.obs import trace as _trace
 
 DEFAULT_MAXSIZE = 256
@@ -207,7 +208,9 @@ class PlanCache:
                 if _verify_flags.verify_plans:
                     _verify_hit(plan, a)
                 return plan
-        r = policy.resolve(a)
+        with build_step("resolve") as st:
+            r = policy.resolve(a)
+            st.method = r.method
         key = (raw[0], a.shape, a.nnz_pad, r.method, r.t, r.tl, r.l_pad,
                policy.with_transpose)
         with self._lock:

@@ -130,4 +130,5 @@ def flash_attention_pallas(q, k, v, *, bq: int = DEFAULT_BQ,
             pltpu.VMEM((bq, dh), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

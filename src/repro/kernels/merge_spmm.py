@@ -65,6 +65,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.csr import CSR, rows_from_row_ptr
 from repro.core.epilogue import apply_epilogue
+from repro.obs import scope
 
 # Default tile sizes: TN = 128 lanes (the "warp width" / coalescing unit),
 # TM = 8 sublanes.  T, the nonzeroes per chunk (the paper's blockDim.x work
@@ -342,16 +343,19 @@ def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
     SMEM).
     """
     out_dtype = b.dtype if out_dtype is None else out_dtype
-    b = b.astype(jnp.float32)       # one-row slices need a 32-bit panel
+    with scope("widen_b"):
+        b = b.astype(jnp.float32)   # one-row slices need a 32-bit panel
     batch, k, n = b.shape
     n_chunks, t = plan["cols"].shape
     tk, n_k = resolve_tk(k, tk)
     kpad = n_k * tk - k
     if kpad:
-        b = jnp.pad(b, ((0, 0), (0, kpad), (0, 0)))
-    count = chunk_counts(plan["slot_nz"], vals.shape[0])
-    per_chunk = [x.reshape(n_chunks, 1, t) for x in
-                 (plan["cols"], plan["lrow"], smem_vals(plan, vals))]
+        with scope("pad"):
+            b = jnp.pad(b, ((0, 0), (0, kpad), (0, 0)))
+    with scope("gather_vals"):
+        count = chunk_counts(plan["slot_nz"], vals.shape[0])
+        per_chunk = [x.reshape(n_chunks, 1, t) for x in
+                     (plan["cols"], plan["lrow"], smem_vals(plan, vals))]
     ep = epilogue
     grid = (batch, n // tn, n_chunks, n_k)
     chunk_spec = pl.BlockSpec((1, 1, t), lambda bb, j, c, kk, *_: (c, 0, 0),
@@ -378,12 +382,14 @@ def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
     )
     kernel = functools.partial(_merge_kernel, tk=tk, n_k=n_k,
                                acc_dtype=acc_dtype, ep=ep)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
-        interpret=interpret,
-    )(plan["tile"], plan["first"], plan["last"], count, *operands)
+    with scope("kernel"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
+            interpret=interpret,
+            name="merge_spmm",
+        )(plan["tile"], plan["first"], plan["last"], count, *operands)
 
 
 # ----------------------------------------------------- static launch model ---

@@ -112,4 +112,5 @@ def moe_group_gemm_pallas(x: jax.Array, w: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tokens, d_out), x.dtype),
         interpret=interpret,
+        name="moe_gemm",
     )(block_expert, x, w)

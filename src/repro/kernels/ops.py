@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core.csr import CSR
 from repro.core.epilogue import apply_epilogue
+from repro.obs import scope
 from . import flash_attention as _flash
 from . import merge_spmm as _merge
 from . import moe_gemm as _moe
@@ -65,12 +66,14 @@ def merge_spmm(a: CSR, b: jax.Array, *, t: int | None = None,
     if interpret is None:
         interpret = _interpret_default()
     m = a.m
-    b2 = _pad_axis(b, _merge.TN, 1)
+    with scope("pad"):
+        b2 = _pad_axis(b, _merge.TN, 1)
     structure = _merge.plan_merge_structure(a, t=t)
     m_pad = _merge.TM * (-(-m // _merge.TM))
     out = _merge.merge_spmm_pallas(structure, a.vals, b2[None], m_pad,
                                    tk=tk, interpret=interpret)
-    return out[0, :m, : b.shape[1]]
+    with scope("unpad"):
+        return out[0, :m, : b.shape[1]]
 
 
 def rowsplit_spmm(a: CSR, b: jax.Array, *, l_pad: int | None = None,
@@ -118,11 +121,13 @@ def _rowsplit_spmm_jit(a: CSR, b: jax.Array, *, l_pad: int,
         return _ref.spmm_rowsplit_ref(a, b, tl=tl, l_pad=l_pad)
     if interpret is None:
         interpret = _interpret_default()
-    b2 = _pad_axis(b, _rowsplit.TN, 1)
+    with scope("pad"):
+        b2 = _pad_axis(b, _rowsplit.TN, 1)
     structure = _rowsplit.plan_rowsplit_structure(a, l_pad=l_pad, tl=tl)
     out = _rowsplit.rowsplit_spmm_pallas(structure, a.vals, b2[None], tk=tk,
                                          interpret=interpret)
-    return out[0, : a.m, : b.shape[1]]
+    with scope("unpad"):
+        return out[0, : a.m, : b.shape[1]]
 
 
 def _resolve_dtypes(vals, b, acc_dtype, out_dtype):
@@ -139,8 +144,10 @@ def _apply_tail(c, ep, bias, residual):
     generally nonzero and must still be produced."""
     if ep is None:
         return c
-    bias_col = bias.astype(c.dtype)[:, None] if ep.bias else None
-    return apply_epilogue(c, ep, bias_col, residual if ep.residual else None)
+    with scope("unpad"):
+        bias_col = bias.astype(c.dtype)[:, None] if ep.bias else None
+        return apply_epilogue(c, ep, bias_col,
+                              residual if ep.residual else None)
 
 
 def _pad_epilogue_operands(ep, bias, residual, lead, m, n, m_pad, tn):
@@ -150,12 +157,13 @@ def _pad_epilogue_operands(ep, bias, residual, lead, m, n, m_pad, tn):
     if ep is None:
         return extra
     extra["epilogue"] = ep
-    if ep.bias:
-        extra["bias"] = jnp.pad(bias, (0, m_pad - m))
-    if ep.residual:
-        res3 = _lead_fold(jnp.broadcast_to(residual, lead + (m, n)))
-        res3 = jnp.pad(res3, ((0, 0), (0, m_pad - m), (0, 0)))
-        extra["residual"] = _pad_axis(res3, tn, 2)
+    with scope("pad"):
+        if ep.bias:
+            extra["bias"] = jnp.pad(bias, (0, m_pad - m))
+        if ep.residual:
+            res3 = _lead_fold(jnp.broadcast_to(residual, lead + (m, n)))
+            res3 = jnp.pad(res3, ((0, 0), (0, m_pad - m), (0, 0)))
+            extra["residual"] = _pad_axis(res3, tn, 2)
     return extra
 
 
@@ -192,21 +200,24 @@ def merge_execute(structure: dict, vals: jax.Array, b: jax.Array, *, m: int,
         c = jnp.zeros(lead + (m, n), adt)
         return _apply_tail(c, ep, bias, residual).astype(odt)
     if impl == "xla":
-        res = None if ep is None or not ep.residual else \
-            jnp.broadcast_to(residual, lead + (m, n))
-        return _ref.merge_execute_ref(
-            structure, vals, b, m, _merge.TM, epilogue=ep, bias=bias,
-            residual=res, acc_dtype=adt, out_dtype=odt)
+        with scope("kernel"):
+            res = None if ep is None or not ep.residual else \
+                jnp.broadcast_to(residual, lead + (m, n))
+            return _ref.merge_execute_ref(
+                structure, vals, b, m, _merge.TM, epilogue=ep, bias=bias,
+                residual=res, acc_dtype=adt, out_dtype=odt)
     if interpret is None:
         interpret = _interpret_default()
-    b3 = _pad_axis(_lead_fold(b), _merge.TN, 2)
+    with scope("pad"):
+        b3 = _pad_axis(_lead_fold(b), _merge.TN, 2)
     m_pad = _merge.TM * (-(-m // _merge.TM))
     extra = _pad_epilogue_operands(ep, bias, residual, lead, m, n, m_pad,
                                    _merge.TN)
     out = _merge.merge_spmm_pallas(structure, vals, b3, m_pad, tk=tk,
                                    interpret=interpret, acc_dtype=adt,
                                    out_dtype=odt, **extra)
-    return out[:, :m, :n].reshape(lead + (m, n))
+    with scope("unpad"):
+        return out[:, :m, :n].reshape(lead + (m, n))
 
 
 @functools.partial(jax.jit,
@@ -231,21 +242,24 @@ def rowsplit_execute(structure: dict, vals: jax.Array, b: jax.Array, *,
         c = jnp.zeros(lead + (m, n), adt)
         return _apply_tail(c, ep, bias, residual).astype(odt)
     if impl == "xla":
-        res = None if ep is None or not ep.residual else \
-            jnp.broadcast_to(residual, lead + (m, n))
-        return _ref.rowsplit_execute_ref(
-            structure, vals, b, m, epilogue=ep, bias=bias, residual=res,
-            acc_dtype=adt, out_dtype=odt)
+        with scope("kernel"):
+            res = None if ep is None or not ep.residual else \
+                jnp.broadcast_to(residual, lead + (m, n))
+            return _ref.rowsplit_execute_ref(
+                structure, vals, b, m, epilogue=ep, bias=bias, residual=res,
+                acc_dtype=adt, out_dtype=odt)
     if interpret is None:
         interpret = _interpret_default()
-    b3 = _pad_axis(_lead_fold(b), _rowsplit.TN, 2)
+    with scope("pad"):
+        b3 = _pad_axis(_lead_fold(b), _rowsplit.TN, 2)
     m_pad = structure["cols"].shape[0]
     extra = _pad_epilogue_operands(ep, bias, residual, lead, m, n, m_pad,
                                    _rowsplit.TN)
     out = _rowsplit.rowsplit_spmm_pallas(structure, vals, b3, tk=tk,
                                          interpret=interpret, acc_dtype=adt,
                                          out_dtype=odt, **extra)
-    return out[:, :m, :n].reshape(lead + (m, n))
+    with scope("unpad"):
+        return out[:, :m, :n].reshape(lead + (m, n))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "impl"))
@@ -268,19 +282,22 @@ def sddmm(rows: jax.Array, cols: jax.Array, valid: jax.Array, dc: jax.Array,
         # has nothing to chunk).
         return jnp.zeros(lead + (nnz_pad,), dc.dtype)
     if impl == "xla":
-        return _ref.sddmm_ref(rows, cols, valid, dc, b)
+        with scope("kernel"):
+            return _ref.sddmm_ref(rows, cols, valid, dc, b)
     if interpret is None:
         interpret = _interpret_default()
     tq = _sddmm.TQ
     p = -(-nnz_pad // tq)
-    rows2 = _pad_axis(rows, tq, 0).reshape(p, tq)
-    cols2 = _pad_axis(cols, tq, 0).reshape(p, tq)
-    dc3 = _pad_axis(_lead_fold(dc), _sddmm.TN, 2)
-    b3 = _pad_axis(_lead_fold(b), _sddmm.TN, 2)
+    with scope("pad"):
+        rows2 = _pad_axis(rows, tq, 0).reshape(p, tq)
+        cols2 = _pad_axis(cols, tq, 0).reshape(p, tq)
+        dc3 = _pad_axis(_lead_fold(dc), _sddmm.TN, 2)
+        b3 = _pad_axis(_lead_fold(b), _sddmm.TN, 2)
     out = _sddmm.sddmm_pallas(rows2, cols2, dc3, b3, interpret=interpret)
-    dvals = out.reshape(out.shape[0], -1)[:, :nnz_pad]
-    return jnp.where(valid, dvals.reshape(lead + (nnz_pad,)),
-                     0).astype(dc.dtype)
+    with scope("unpad"):
+        dvals = out.reshape(out.shape[0], -1)[:, :nnz_pad]
+        return jnp.where(valid, dvals.reshape(lead + (nnz_pad,)),
+                         0).astype(dc.dtype)
 
 
 # ---------------------------------------------------- explicit vmap rules ---

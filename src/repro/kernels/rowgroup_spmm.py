@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import scope
+
 from . import ops as _ops
 from . import registry as _registry
 from .merge_spmm import default_t
@@ -135,8 +137,9 @@ def rowgroup_execute_parts(groups_meta: tuple, tl: int, fwd: dict,
             group_out = adt
         if ep.bias:
             m = fwd["inv_pos"].shape[0]
-            bias_perm = jnp.zeros((m,), bias.dtype) \
-                .at[fwd["inv_pos"]].set(bias)
+            with scope("pad"):
+                bias_perm = jnp.zeros((m,), bias.dtype) \
+                    .at[fwd["inv_pos"]].set(bias)
     outs = []
     start = 0
     for (m_g, _), gs in zip(groups_meta, fwd["groups"]):
@@ -148,10 +151,11 @@ def rowgroup_execute_parts(groups_meta: tuple, tl: int, fwd: dict,
             out_dtype=group_out))
     if not outs:
         return jnp.zeros(b.shape[:-2] + (0, b.shape[-1]), odt)
-    out = jnp.concatenate(outs, axis=-2) if len(outs) > 1 else outs[0]
-    out = jnp.take(out, fwd["inv_pos"], axis=-2)
-    if ep is not None and ep.residual:
-        out = (out + residual.astype(out.dtype)).astype(odt)
+    with scope("unpad"):
+        out = jnp.concatenate(outs, axis=-2) if len(outs) > 1 else outs[0]
+        out = jnp.take(out, fwd["inv_pos"], axis=-2)
+        if ep is not None and ep.residual:
+            out = (out + residual.astype(out.dtype)).astype(odt)
     return out
 
 

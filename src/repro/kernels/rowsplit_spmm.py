@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.csr import CSR
+from repro.obs import scope
 
 from .merge_spmm import (TM, TN, apply_vals, flush_tile, fold_slots,
                          resolve_tk, scaled_b_row, smem_vals, split_refs)
@@ -171,18 +172,22 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
     and values into SMEM; the per-row lengths are scalar-prefetched.
     """
     out_dtype = b.dtype if out_dtype is None else out_dtype
-    b = b.astype(jnp.float32)       # one-row slices need a 32-bit panel
+    with scope("widen_b"):
+        b = b.astype(jnp.float32)   # one-row slices need a 32-bit panel
     batch, k, n = b.shape
     m_pad, l = plan["cols"].shape
     tk, n_k = resolve_tk(k, tk)
     kpad = n_k * tk - k
     if kpad:
-        b = jnp.pad(b, ((0, 0), (0, kpad), (0, 0)))
+        with scope("pad"):
+            b = jnp.pad(b, ((0, 0), (0, kpad), (0, 0)))
     tw = slot_window(l)
-    lens = row_lengths(plan["slot_nz"], vals.shape[0])
-    slots = [plan["cols"], smem_vals(plan, vals)]
+    with scope("gather_vals"):
+        lens = row_lengths(plan["slot_nz"], vals.shape[0])
+        slots = [plan["cols"], smem_vals(plan, vals)]
     if l % tw:
-        slots = [jnp.pad(x, ((0, 0), (0, tw - l % tw))) for x in slots]
+        with scope("pad"):
+            slots = [jnp.pad(x, ((0, 0), (0, tw - l % tw))) for x in slots]
     n_l = slots[0].shape[1] // tw
     ep = epilogue
     grid = (batch, m_pad // tm, n // tn, n_l, n_k)
@@ -203,19 +208,21 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
         operands.append(residual)
     kernel = functools.partial(_rowsplit_kernel, tm=tm, tw=tw, tk=tk,
                                n_k=n_k, acc_dtype=acc_dtype, ep=ep)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, tm, tn),
-                                   lambda bb, i, j, ll, kk, lens:
-                                   (bb, i, j)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)]),
-        out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
-        interpret=interpret,
-    )(lens, *operands)
+    with scope("kernel"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((1, tm, tn),
+                                       lambda bb, i, j, ll, kk, lens:
+                                       (bb, i, j)),
+                scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)]),
+            out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
+            interpret=interpret,
+            name="rowsplit_spmm",
+        )(lens, *operands)
 
 
 # ----------------------------------------------------- static launch model ---

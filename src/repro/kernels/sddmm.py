@@ -31,6 +31,8 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.obs import scope
+
 TN = 128   # lanes of the dense axis per grid step
 TQ = 128   # nonzeroes per chunk
 
@@ -69,20 +71,22 @@ def sddmm_pallas(rows: jax.Array, cols: jax.Array, dc: jax.Array,
     grid = (batch, p, n // tn)
     kernel = functools.partial(_sddmm_kernel, n_j=n // tn,
                                acc_dtype=acc_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tq), lambda bb, i, j: (i, 0)),
-            pl.BlockSpec((1, tq), lambda bb, i, j: (i, 0)),
-            pl.BlockSpec((1, m, tn), lambda bb, i, j: (bb, 0, j)),
-            pl.BlockSpec((1, k, tn), lambda bb, i, j: (bb, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, tq), lambda bb, i, j: (bb, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, p, tq), acc_dtype),
-        scratch_shapes=[pltpu.VMEM((1, tq), acc_dtype)],
-        interpret=interpret,
-    )(rows, cols, dc, b)
+    with scope("kernel"):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, tq), lambda bb, i, j: (i, 0)),
+                pl.BlockSpec((1, tq), lambda bb, i, j: (i, 0)),
+                pl.BlockSpec((1, m, tn), lambda bb, i, j: (bb, 0, j)),
+                pl.BlockSpec((1, k, tn), lambda bb, i, j: (bb, 0, j)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, tq), lambda bb, i, j: (bb, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((batch, p, tq), acc_dtype),
+            scratch_shapes=[pltpu.VMEM((1, tq), acc_dtype)],
+            interpret=interpret,
+            name="sddmm",
+        )(rows, cols, dc, b)
 
 
 # ----------------------------------------------------- static launch model ---

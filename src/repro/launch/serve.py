@@ -122,9 +122,11 @@ def make_pruned_forward(cfg):
         h = M.embed_inputs(params, cfg, {"tokens": tokens})
         for btype, lp in zip(btypes, blocks):
             h, _, _ = M.block_apply(lp, btype, h, cfg)
-        h = L.norm_apply(params["final_norm"], h, cfg.norm)
-        return h.astype(jnp.float32) @ M.unembed_matrix(
-            params, cfg).T.astype(jnp.float32)
+        with obs.scope("norm"):
+            h = L.norm_apply(params["final_norm"], h, cfg.norm)
+        with obs.scope("unembed"):
+            return h.astype(jnp.float32) @ M.unembed_matrix(
+                params, cfg).T.astype(jnp.float32)
 
     return fwd
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.obs import scope
 from . import layers as L
 from . import moe as M
 from . import rglru as R
@@ -79,40 +80,52 @@ def block_apply(p, btype, x, cfg, *, positions=None, cache=None, pos=None):
     # here, where primal & cotangent are bf16 — otherwise the partitioner
     # reshards the f32 carrier downstream at 2× wire (§Perf iteration 3).
     rs = cfg.residual_spec
+    # Layer scopes (repro.obs.SCOPES) label every device op for the
+    # profiler join; each sublayer's residual add rides in its scope.
     if btype in ("attn", "moe"):
-        h = constrain(L.norm_apply(p["ln1"], x, cfg.norm), *rs)
-        attn_out, new_cache = L.attention_apply(
-            p["attn"], h, cfg, window=_window(cfg), positions=positions,
-            cache=cache, pos=pos)
+        with scope("norm"):
+            h = constrain(L.norm_apply(p["ln1"], x, cfg.norm), *rs)
+        with scope("attn"):
+            attn_out, new_cache = L.attention_apply(
+                p["attn"], h, cfg, window=_window(cfg), positions=positions,
+                cache=cache, pos=pos)
         if getattr(cfg, "parallel_block", False):
-            if btype == "moe":
-                ffn_out, aux = M.moe_apply(p["moe"], h, cfg)
-            else:
-                ffn_out = L.mlp_apply(p["mlp"], h, cfg)
-            x = x + attn_out + ffn_out
+            with scope("mlp"):
+                if btype == "moe":
+                    ffn_out, aux = M.moe_apply(p["moe"], h, cfg)
+                else:
+                    ffn_out = L.mlp_apply(p["mlp"], h, cfg)
+                x = x + attn_out + ffn_out
         else:
-            x = x + attn_out
-            h2 = constrain(L.norm_apply(p["ln2"], x, cfg.norm), *rs)
-            if btype == "moe":
-                ffn_out, aux = M.moe_apply(p["moe"], h2, cfg)
-            else:
-                ffn_out = L.mlp_apply(p["mlp"], h2, cfg)
-            x = x + ffn_out
+            with scope("attn"):
+                x = x + attn_out
+            with scope("norm"):
+                h2 = constrain(L.norm_apply(p["ln2"], x, cfg.norm), *rs)
+            with scope("mlp"):
+                if btype == "moe":
+                    ffn_out, aux = M.moe_apply(p["moe"], h2, cfg)
+                else:
+                    ffn_out = L.mlp_apply(p["mlp"], h2, cfg)
+                x = x + ffn_out
         return x, new_cache, aux
     # Recurrent blocks: a multi-token input is a prefill — the state is
     # computed from scratch (the passed cache is only a shape donor).
     if btype == "ssd":
-        h = L.norm_apply(p["ln1"], x, cfg.norm)
+        with scope("norm"):
+            h = L.norm_apply(p["ln1"], x, cfg.norm)
         state = cache if (cache is not None and x.shape[1] == 1) else None
         out, new_cache = S.ssd_apply(p["ssd"], h, cfg, state=state)
         return x + out, new_cache, aux
     if btype == "rglru":
-        h = L.norm_apply(p["ln1"], x, cfg.norm)
+        with scope("norm"):
+            h = L.norm_apply(p["ln1"], x, cfg.norm)
         state = cache if (cache is not None and x.shape[1] == 1) else None
         out, new_cache = R.rglru_apply(p["rec"], h, cfg, state=state)
         x = x + out
-        h2 = L.norm_apply(p["ln2"], x, cfg.norm)
-        x = x + L.mlp_apply(p["mlp"], h2, cfg)
+        with scope("norm"):
+            h2 = L.norm_apply(p["ln2"], x, cfg.norm)
+        with scope("mlp"):
+            x = x + L.mlp_apply(p["mlp"], h2, cfg)
         return x, new_cache, aux
     raise ValueError(btype)
 
@@ -202,18 +215,19 @@ def forward(params, cfg, h, *, positions=None, caches=None, pos=None,
 
 
 def embed_inputs(params, cfg, batch: dict, *, positions=None):
-    if cfg.input_mode == "tokens":
-        h = jnp.take(params["embed"], batch["tokens"], axis=0)
-        h = h.astype(cfg.cdtype)
-        if getattr(cfg, "embed_scale", False):
-            h = h * jnp.asarray(cfg.d_model ** 0.5, cfg.cdtype)
-    else:
-        h = batch["embeds"].astype(cfg.cdtype)
-    if cfg.rope_theta == 0.0:  # sinusoidal absolute positions (musicgen)
-        if positions is None:
-            positions = jnp.arange(h.shape[1])[None, :]
-        h = h + L.sinusoidal(positions, cfg.d_model).astype(h.dtype)
-    return h
+    with scope("embed"):
+        if cfg.input_mode == "tokens":
+            h = jnp.take(params["embed"], batch["tokens"], axis=0)
+            h = h.astype(cfg.cdtype)
+            if getattr(cfg, "embed_scale", False):
+                h = h * jnp.asarray(cfg.d_model ** 0.5, cfg.cdtype)
+        else:
+            h = batch["embeds"].astype(cfg.cdtype)
+        if cfg.rope_theta == 0.0:  # sinusoidal absolute positions (musicgen)
+            if positions is None:
+                positions = jnp.arange(h.shape[1])[None, :]
+            h = h + L.sinusoidal(positions, cfg.d_model).astype(h.dtype)
+        return h
 
 
 def unembed_matrix(params, cfg):

@@ -26,6 +26,7 @@ import numpy as np
 from repro.core import (CSR, ExecutionConfig, Heuristic, PlanPolicy,
                         SparseMatrix, SpmmPlan)
 from repro.core.config import _UNSET, _warn_deprecated
+from repro.obs import scope
 
 # Below this many tokens per call, flattening the leading axes packs the
 # tokens densely into the kernels' TN=128-lane tiles; from here up each
@@ -146,19 +147,27 @@ class SparseLinear:
         w = layer.weight
         out_dtype = x.dtype if exec is None or exec.out_dtype is None \
             else jnp.dtype(exec.out_dtype)
-        if x.ndim >= 3 and x.shape[-2] >= BATCHED_MIN_TOKENS:
-            xt = jnp.swapaxes(x, -1, -2).astype(w.dtype)  # (..., d_in, tok)
-            res = None if residual is None else \
-                jnp.swapaxes(residual, -1, -2)
+        # The layout around the product belongs to the SpMM call's scopes:
+        # B laid out and widened in, C laid back out.
+        with scope("spmm"):
+            if x.ndim >= 3 and x.shape[-2] >= BATCHED_MIN_TOKENS:
+                with scope("widen_b"):                # (..., d_in, tok)
+                    xt = jnp.swapaxes(x, -1, -2).astype(w.dtype)
+                with scope("pad"):
+                    res = None if residual is None else \
+                        jnp.swapaxes(residual, -1, -2)
+                y = mtx.matmul(xt, exec, bias=bias, residual=res, **kw)
+                with scope("unpad"):
+                    return jnp.swapaxes(y, -1, -2).astype(out_dtype)
+            lead = x.shape[:-1]
+            with scope("widen_b"):                    # (d_in, tokens) = B
+                xt = x.reshape(-1, x.shape[-1]).T.astype(w.dtype)
+            with scope("pad"):                        # (d_out, tokens) = C
+                res = None if residual is None else \
+                    residual.reshape(-1, w.m).T
             y = mtx.matmul(xt, exec, bias=bias, residual=res, **kw)
-            return jnp.swapaxes(y, -1, -2).astype(out_dtype)
-        lead = x.shape[:-1]
-        xt = x.reshape(-1, x.shape[-1]).T          # (d_in, tokens) = B
-        res = None if residual is None else \
-            residual.reshape(-1, w.m).T            # (d_out, tokens) = C
-        y = mtx.matmul(xt.astype(w.dtype), exec, bias=bias, residual=res,
-                       **kw)
-        return y.T.reshape(*lead, w.m).astype(out_dtype)
+            with scope("unpad"):
+                return y.T.reshape(*lead, w.m).astype(out_dtype)
 
 
 jax.tree_util.register_pytree_node(

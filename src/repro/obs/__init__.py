@@ -1,44 +1,48 @@
-"""Engine-wide observability: tracing, metrics, roofline accounting.
+"""Engine-wide observability: tracing, metrics, device-op scopes.
 
-Three legs, all zero-cost when disabled:
-
-* :mod:`repro.obs.trace` — scoped spans/instant events in a ring buffer,
-  exportable as Chrome trace-event JSON (Perfetto-viewable).  Enable via
+* :mod:`repro.obs.trace` — scoped spans and instant events.  Every span
+  enters a ``jax.profiler.TraceAnnotation`` whenever a profiler records,
+  so any ``jax.profiler`` capture holds the program's spans on the host
+  clock;
+  the in-memory ring and its Chrome trace-event export stay behind
   ``REPRO_TRACE=1``, :func:`enable`, or ``with obs.tracing(): ...``.
 * :mod:`repro.obs.metrics` — typed counter/gauge/histogram families with
   labels on the process-global :data:`registry`; ``report()`` /
   ``dump_metrics()`` expose them.
-* :mod:`repro.obs.roofline` — bytes/flops models + the global
-  :data:`accountant` relating measured wall time to modeled minimum
-  traffic, as a fraction of a measured streaming roof.
+* :mod:`repro.obs.scopes` — the program's ``jax.named_scope`` vocabulary
+  (:data:`SCOPES`, opened through :func:`scope`, always on) and
+  :func:`device_scopes`, which maps a compiled program's device ops to
+  it: the way to read a profiler capture by layer.
+* :mod:`repro.obs.roofline` — compulsory-bytes models of SpMM, which the
+  static traffic analyzer holds programs against.
 
 This package imports only the stdlib at module load (jax is imported
-lazily inside the roofline calibrator and profiler annotations), so core
-engine modules may import it freely without cycles.
+lazily by profiler annotations, scopes and the HLO join), so core engine
+modules may import it freely without cycles.
 """
 from __future__ import annotations
 
 from . import trace as trace
 from .metrics import (Counter, Gauge, Histogram, MetricFamily,
                       MetricsRegistry)
-from .roofline import (Roof, RooflineAccountant, fused_epilogue_ceiling,
-                       measure_roof, plan_bwd_min_bytes, plan_min_bytes,
-                       sddmm_min_bytes, spmm_flops, spmm_min_bytes)
+from .roofline import (fused_epilogue_ceiling, plan_bwd_min_bytes,
+                       plan_min_bytes, sddmm_min_bytes, spmm_flops,
+                       spmm_min_bytes)
+from .scopes import KERNELS, SCOPES, device_scopes, scope
 from .trace import (Tracer, disable, enable, event, get_tracer, is_enabled,
                     span, tracing)
 
-# Process-global instances: instrumentation sites across the engine share
-# these without import-order coupling.
+# Process-global registry: instrumentation sites across the engine share
+# it without import-order coupling.
 registry = MetricsRegistry()
-accountant = RooflineAccountant()
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricFamily", "MetricsRegistry",
-    "Roof", "RooflineAccountant", "Tracer", "accountant", "disable",
+    "Counter", "Gauge", "Histogram", "KERNELS", "MetricFamily",
+    "MetricsRegistry", "SCOPES", "Tracer", "device_scopes", "disable",
     "dump_metrics", "enable", "event", "fused_epilogue_ceiling",
-    "get_tracer", "is_enabled", "measure_roof", "plan_bwd_min_bytes",
-    "plan_min_bytes", "registry", "report", "reset", "sddmm_min_bytes",
-    "span", "spmm_flops", "spmm_min_bytes", "trace", "tracing",
+    "get_tracer", "is_enabled", "plan_bwd_min_bytes", "plan_min_bytes",
+    "registry", "report", "reset", "scope", "sddmm_min_bytes", "span",
+    "spmm_flops", "spmm_min_bytes", "trace", "tracing",
 ]
 
 
@@ -62,13 +66,9 @@ def _rung_rates() -> dict[str, float]:
     return {r: n / total for r, n in sorted(by_rung.items())}
 
 
-def report(*, roof: Roof | None = None) -> str:
-    """Text snapshot of the whole subsystem: metrics exposition,
-    ladder-rung hit rates, and the roofline accountant's verdicts.
-
-    Pass a :class:`Roof` (from :func:`measure_roof`) to get
-    percent-of-roof numbers; omitted, achieved bandwidth still prints.
-    """
+def report() -> str:
+    """Text snapshot of the whole subsystem: ladder-rung hit rates, the
+    metrics exposition and the tracer's state."""
     parts = []
     rates = _rung_rates()
     if rates:
@@ -79,8 +79,6 @@ def report(*, roof: Roof | None = None) -> str:
     if m:
         parts.append("== metrics ==")
         parts.append(m)
-    parts.append("== roofline ==")
-    parts.append(accountant.report(roof))
     tr = get_tracer()
     if tr is not None:
         parts.append(f"== trace == {len(tr)} events buffered"
@@ -89,9 +87,8 @@ def report(*, roof: Roof | None = None) -> str:
 
 
 def reset() -> None:
-    """Zero metrics + roofline entries; clear the tracer ring (tests)."""
+    """Zero metrics; clear the tracer ring (tests)."""
     registry.reset()
-    accountant.reset()
     tr = get_tracer()
     if tr is not None:
         tr.clear()

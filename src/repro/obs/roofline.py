@@ -1,37 +1,19 @@
-"""Live roofline accountant: measured wall time vs. modeled minimum bytes.
+"""Compulsory-traffic models of SpMM: the bytes each operand and result
+moves across HBM at least once.
 
-The paper's verdict criterion is *distance to the memory-bandwidth roof*:
-merge-based balancing and coalesced access matter exactly because SpMM at
-interesting sparsities is bandwidth-bound.  This module turns that from
-an offline benchmark argument into an engine-wide measurement:
-
-* :func:`spmm_min_bytes` / :func:`plan_min_bytes` — the compulsory-traffic
-  model (each operand/result crosses HBM once; moved here from
-  ``benchmarks/roofline.py``, which now re-exports it),
+* :func:`spmm_min_bytes` / :func:`plan_min_bytes` — one forward call
+  (moved here from ``benchmarks/roofline.py``, which re-exports it),
 * :func:`plan_bwd_min_bytes` / :func:`sddmm_min_bytes` — the same model
   for the custom-VJP backward (transpose-merge dB + SDDMM dvals), the
   floor the static traffic analyzer (``repro.analysis.traffic``) holds
   the backward programs against,
-* :func:`measure_roof` — a streaming (copy-scale) benchmark calibrating
-  the backend's achievable bandwidth once, cached under ``artifacts/``
-  keyed by backend,
-* :class:`RooflineAccountant` — per ``(kind, method, impl, dtype)`` key,
-  accumulates measured wall time next to modeled minimum bytes and
-  reports achieved bandwidth as a fraction of the measured roof:
-  "kernel X ran at Y% of roof".
+* :func:`epilogue_tail_bytes` / :func:`fused_epilogue_ceiling` — what a
+  separate epilogue pass would add, and what fusing it saves.
 
-The fraction is a *lower bound* on efficiency (the model counts
-compulsory bytes only; a kernel moving more than compulsory traffic looks
-worse, never better), which is the honest direction for a verification
-harness: the GPU/TPU port is judged by how close these numbers get to 1.
+Device-time roofline shares are the benchmark's (``bench/work.py``, the
+published peaks in ``bench/peaks.json``), read from a chip trace.
 """
 from __future__ import annotations
-
-import dataclasses
-import json
-import os
-import threading
-import time
 
 # ------------------------------------------------------ bytes/flops model ---
 
@@ -137,219 +119,3 @@ def plan_bwd_min_bytes(meta, n: int, *, val_dtype: str = "float32",
 def spmm_flops(nnz: int, n: int) -> float:
     """Useful flops of one SpMM: a multiply-add per (nonzero, column)."""
     return 2.0 * nnz * n
-
-
-# ------------------------------------------------------- roof calibration ---
-
-
-@dataclasses.dataclass(frozen=True)
-class Roof:
-    """A backend's measured streaming-bandwidth roof."""
-
-    backend: str
-    bytes_per_s: float
-    elements: int                  # array length of the calibration run
-    source: str                    # "measured" | "cached"
-
-    @property
-    def gb_per_s(self) -> float:
-        return self.bytes_per_s / 1e9
-
-
-_ROOF_CACHE_FILE = "roofline_roof.json"
-_roof_memo: dict[str, Roof] = {}
-_roof_lock = threading.Lock()
-
-
-def _measure_stream_bw(elements: int, repeat: int) -> float:
-    """Best-case streaming bandwidth via a jitted copy-scale kernel.
-
-    ``y = x * 1.5 + 0.25`` over an f32 array far larger than L2: one read
-    + one write per element.  The *minimum* wall time over ``repeat``
-    runs is the roof — the question is what the memory system can do, not
-    what it does on an average run.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    x = jnp.ones((elements,), jnp.float32)
-    f = jax.jit(lambda x: x * 1.5 + 0.25)
-    jax.block_until_ready(f(x))           # compile
-    best = float("inf")
-    for _ in range(repeat):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(x))
-        best = min(best, time.perf_counter() - t0)
-    return 2.0 * elements * 4 / best
-
-
-def measure_roof(*, cache_dir: str = "artifacts", force: bool = False,
-                 elements: int = 1 << 24, repeat: int = 5) -> Roof:
-    """The backend's streaming roof, calibrated once and cached.
-
-    Cached two ways: in-process (per backend) and in
-    ``<cache_dir>/roofline_roof.json`` so every bench/serve run on this
-    machine shares one calibration.  ``force`` re-measures.
-    ``cache_dir=None`` skips the file cache.
-    """
-    import jax
-
-    backend = jax.default_backend()
-    with _roof_lock:
-        memo = _roof_memo.get(backend)
-    if memo is not None and not force:
-        return memo
-    path = (os.path.join(cache_dir, _ROOF_CACHE_FILE)
-            if cache_dir else None)
-    if path and not force and os.path.exists(path):
-        try:
-            with open(path) as f:
-                data = json.load(f)
-            rec = data.get(backend)
-            if rec and rec.get("bytes_per_s", 0) > 0:
-                roof = Roof(backend=backend,
-                            bytes_per_s=float(rec["bytes_per_s"]),
-                            elements=int(rec.get("elements", elements)),
-                            source="cached")
-                with _roof_lock:
-                    _roof_memo[backend] = roof
-                return roof
-        except (OSError, ValueError, KeyError):
-            pass                    # unreadable cache: re-measure
-    bw = _measure_stream_bw(elements, repeat)
-    roof = Roof(backend=backend, bytes_per_s=bw, elements=elements,
-                source="measured")
-    with _roof_lock:
-        _roof_memo[backend] = roof
-    if path:
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            data = {}
-            if os.path.exists(path):
-                try:
-                    with open(path) as f:
-                        data = json.load(f)
-                except (OSError, ValueError):
-                    data = {}
-            data[backend] = {"bytes_per_s": bw, "elements": elements,
-                             "measured_at": time.time()}
-            with open(path, "w") as f:
-                json.dump(data, f, indent=1)
-        except OSError:
-            pass                    # read-only checkout: memo still holds
-    return roof
-
-
-def clear_roof_memo() -> None:
-    """Forget in-process roof calibrations (tests)."""
-    with _roof_lock:
-        _roof_memo.clear()
-
-
-# ------------------------------------------------------------- accountant ---
-
-
-@dataclasses.dataclass
-class _Entry:
-    calls: int = 0
-    wall_us: float = 0.0
-    min_bytes: float = 0.0
-    flops: float = 0.0
-    hlo_bytes: float = 0.0          # optional: parsed-HLO traffic
-
-
-class RooflineAccountant:
-    """Accumulates (measured wall, modeled bytes) per execution key.
-
-    Keys are ``(kind, method, impl, dtype)`` tuples — e.g. ``("spmm",
-    "merge", "xla", "float32")``.  Feed it from any site that owns a wall
-    time for a known program: benchmark loops (``benchmarks/bench_obs``),
-    serve sessions, tuner sweeps.
-    """
-
-    def __init__(self):
-        self._entries: dict[tuple, _Entry] = {}
-        self._lock = threading.Lock()
-
-    def record(self, key: tuple, *, wall_us: float, min_bytes: float,
-               flops: float = 0.0, calls: int = 1,
-               hlo_bytes: float = 0.0) -> None:
-        """Add ``calls`` executions totaling ``wall_us`` that each moved
-        at least ``min_bytes / calls`` compulsory bytes."""
-        with self._lock:
-            e = self._entries.setdefault(tuple(key), _Entry())
-            e.calls += calls
-            e.wall_us += wall_us
-            e.min_bytes += min_bytes
-            e.flops += flops
-            e.hlo_bytes += hlo_bytes
-
-    def account_plan(self, meta, n: int, *, wall_us: float,
-                     impl: str = "pallas", val_dtype: str = "float32",
-                     out_dtype: str | None = None, calls: int = 1,
-                     kind: str = "spmm", hlo_bytes: float = 0.0) -> None:
-        """Record executions of a plan (``meta``: PlanMeta/ShardedMeta)
-        against an n-column B, deriving bytes/flops from the model."""
-        method = getattr(meta, "method", "?")
-        per_call = plan_min_bytes(meta, n, val_dtype=val_dtype,
-                                  out_dtype=out_dtype)
-        self.record((kind, method, impl, str(val_dtype)),
-                    wall_us=wall_us, min_bytes=per_call * calls,
-                    flops=spmm_flops(meta.nnz_pad, n) * calls,
-                    calls=calls, hlo_bytes=hlo_bytes)
-
-    def rows(self, roof: Roof | None = None) -> list[dict]:
-        """One dict per key: achieved bandwidth, roof fraction, flops."""
-        with self._lock:
-            items = sorted(self._entries.items())
-        out = []
-        for key, e in items:
-            secs = e.wall_us / 1e6
-            bw = e.min_bytes / secs if secs > 0 else 0.0
-            row = {
-                "kind": key[0],
-                "method": key[1] if len(key) > 1 else "",
-                "impl": key[2] if len(key) > 2 else "",
-                "dtype": key[3] if len(key) > 3 else "",
-                "calls": e.calls,
-                "wall_us": e.wall_us,
-                "min_bytes": e.min_bytes,
-                "achieved_bytes_per_s": bw,
-                "gflops_per_s": (e.flops / secs / 1e9) if secs > 0 else 0.0,
-            }
-            if e.hlo_bytes:
-                row["hlo_bytes"] = e.hlo_bytes
-            if roof is not None and roof.bytes_per_s > 0:
-                row["roof_bytes_per_s"] = roof.bytes_per_s
-                row["roof_fraction"] = bw / roof.bytes_per_s
-            out.append(row)
-        return out
-
-    def report(self, roof: Roof | None = None) -> str:
-        """Text verdicts: "kernel X ran at Y% of roof"."""
-        rows = self.rows(roof)
-        if not rows:
-            return "roofline: no executions recorded"
-        lines = []
-        if roof is not None:
-            lines.append(
-                f"roofline roof ({roof.backend}, {roof.source}): "
-                f"{roof.gb_per_s:.2f} GB/s streaming")
-        for r in rows:
-            head = (f"{r['kind']} {r['method']}/{r['impl']} {r['dtype']}: "
-                    f"{r['achieved_bytes_per_s'] / 1e9:.2f} GB/s achieved")
-            if "roof_fraction" in r:
-                head += f" = {r['roof_fraction'] * 100:.1f}% of roof"
-            head += (f" ({r['calls']} calls, "
-                     f"{r['min_bytes'] / max(r['calls'], 1) / 1e6:.2f} "
-                     "MB/call min)")
-            lines.append(head)
-        return "\n".join(lines)
-
-    def reset(self) -> None:
-        with self._lock:
-            self._entries.clear()
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._entries)

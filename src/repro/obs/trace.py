@@ -1,23 +1,30 @@
 """Structured tracing: scoped spans + instant events in a ring buffer.
 
-Zero-cost when disabled: every instrumentation site guards on the
+Every span enters a ``jax.profiler.TraceAnnotation`` for its duration,
+whether or not the ring is on: the annotation checks whether a profiler
+is recording and otherwise does nothing, so
+any ``jax.profiler`` capture — the benchmark's ``--trace 1`` or an
+operator's — holds the program's spans on the host clock the device ops
+are moved onto.  Device ops are read by layer through the program's
+always-on ``jax.named_scope`` vocabulary (:mod:`repro.obs.scopes`,
+:func:`repro.obs.device_scopes`).
+
+The in-memory ring is gated: every instrumentation site guards on the
 module-level ``_enabled`` flag (one attribute read), so the engine's warm
-execute path pays nothing while observability is off.  When enabled
+execute path records nothing while it is off.  When enabled
 (``REPRO_TRACE=1`` in the environment, :func:`enable`, or the
 :func:`tracing` context manager), spans land in a bounded ring buffer as
 Chrome trace-event records — exportable with :meth:`Tracer.export` and
 viewable in Perfetto / ``chrome://tracing``.
 
-Spans double as ``jax.profiler.TraceAnnotation`` scopes (when jax is
-importable), so host-side engine phases — plan resolution, plan builds,
-kernel dispatch — line up against XLA device activity inside a
-``jax.profiler.trace`` capture.
-
-The emitting sites (see ``core/config.py``, ``engine/cache.py``,
-``core/spmm.py``, ``distributed/spmm.py``) use four categories:
+The emitting sites (see ``core/config.py``, ``core/plan.py``,
+``engine/cache.py``, ``core/spmm.py``, ``distributed/spmm.py``) use four
+categories:
 
 * ``plan``     — ``PlanPolicy.resolve`` (which ladder rung fired),
-  ``plan.build``, sharded plan assembly,
+  ``plan.build``, the build steps (``plan.step.fingerprint`` and
+  ``plan.step.resolve`` before the build, ``plan.step.structure`` and
+  ``plan.step.row_ids`` inside it), sharded plan assembly,
 * ``cache``    — plan-cache hit / miss / eviction,
 * ``dispatch`` — kernel dispatch (method, impl, dtypes, epilogue, tk),
 * ``serve`` / ``train`` — launcher request/step scopes.
@@ -114,60 +121,61 @@ class Tracer:
 # ------------------------------------------------------------ span scopes ---
 
 
-def _annotation(name: str):
-    """A jax.profiler.TraceAnnotation for ``name``, or None off-jax."""
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return None
+_profiler = None
+
+
+def _trace_me():
+    """JAX's ``TraceAnnotation`` class (a profiler ``TraceMe``), or False
+    where jax cannot be imported."""
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax.profiler
+            _profiler = jax.profiler.TraceAnnotation
+        except Exception:
+            _profiler = False
+    return _profiler
 
 
 class _Span:
-    """A live span: records a complete ("X") event on exit.
+    """A live span: a profiler annotation for its duration (while a
+    profiler records) and, when the ring is on, a complete ("X") event on
+    exit.
 
     ``set(**kw)`` adds args after entry (e.g. the resolution rung, known
-    only mid-body).  Also enters a ``jax.profiler.TraceAnnotation`` so the
-    span shows up inside XLA profiler captures.
+    only mid-body).
     """
 
     __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
-    def __init__(self, name: str, cat: str, args: dict):
+    def __init__(self, name: str, cat: str, args: dict, ann):
         self.name = name
         self.cat = cat
         self.args = args
-        self._t0 = 0.0
-        self._ann = None
+        self._t0 = None
+        self._ann = ann
 
     def set(self, **kw) -> None:
         self.args.update(kw)
 
     def __enter__(self) -> "_Span":
-        self._ann = _annotation(self.name)
         if self._ann is not None:
-            try:
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
-        self._t0 = _now_us()
+            self._ann.__enter__()
+        if _enabled:
+            self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc) -> None:
-        t1 = _now_us()
         if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:
-                pass
+            self._ann.__exit__(*exc)
         tr = _tracer
-        if tr is not None:
-            tr.add_complete(self.name, self.cat, self._t0, t1 - self._t0,
-                            self.args)
+        if self._t0 is not None and tr is not None:
+            tr.add_complete(self.name, self.cat, self._t0,
+                            _now_us() - self._t0, self.args)
 
 
 class _NullSpan:
-    """Disabled-path span: a shared, do-nothing context manager."""
+    """Neither ring nor profiler: a shared, do-nothing context manager."""
 
     __slots__ = ()
 
@@ -187,11 +195,15 @@ _NULL_SPAN = _NullSpan()
 def span(name: str, cat: str = "", **args):
     """A scoped span — ``with obs.span("plan.build", cat="plan", ...):``.
 
-    Returns a shared null context when tracing is disabled (no event, no
-    timestamps, no annotation)."""
-    if not _enabled:
+    A profiler annotation whenever a ``jax.profiler`` capture is
+    recording, whether or not tracing is enabled; recorded in the ring
+    (with ``args``) while tracing is enabled.  With neither, the shared
+    null span, after one profiler check."""
+    tm = _trace_me()
+    ann = tm(name) if tm and tm.is_enabled() else None
+    if ann is None and not _enabled:
         return _NULL_SPAN
-    return _Span(name, cat, args)
+    return _Span(name, cat, args, ann)
 
 
 def event(name: str, cat: str = "", **args) -> None:

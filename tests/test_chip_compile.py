@@ -5,13 +5,16 @@ Mosaic (the TPU kernel compiler) whether a block shape, a gather or an
 on-chip footprint is legal.  These tests do: each compiles one forward
 kernel for a described (not attached) v5e chip at a llama3.2-1b MLP shape
 with 25% of the weights kept, and checks that the compiled program holds
-the kernel as a ``tpu_custom_call``.  Nothing runs, so this says nothing
-about results or speed.
+the kernel as a ``tpu_custom_call`` named after the kernel's ``name=``
+(what the benchmark's trace reduction finds it by).  Nothing runs, so
+this says nothing about results or speed.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under several test workers a
 module that touched it on import would change what each worker collects.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -66,10 +69,15 @@ def _on(sharding, tree):
 
 
 def _compile(fn, sharding, structure, a, k):
+    """The instruction names of the compiled program's kernels."""
     b = jax.ShapeDtypeStruct((1, k, TOKENS), jnp.float32)
     compiled = jax.jit(fn).lower(*_on(sharding, (structure, a.vals, b))) \
         .compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    names = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*"
+                       r'custom_call_target="tpu_custom_call"',
+                       compiled.as_text(), re.M)
+    assert names
+    return names
 
 
 @pytest.mark.parametrize("label,m,k", _mlp_shapes())
@@ -78,9 +86,10 @@ def test_merge_compiles_for_v5e(one_chip, label, m, k):
     t = merge_spmm.default_t(m, a.nnz_pad)
     structure = jax.eval_shape(
         lambda a: merge_spmm.plan_merge_structure(a, t=t), a)
-    _compile(lambda s, v, b: ops.merge_execute(s, v, b, m=m,
-                                                interpret=False),
-             one_chip, structure, a, k)
+    names = _compile(lambda s, v, b: ops.merge_execute(s, v, b, m=m,
+                                                        interpret=False),
+                     one_chip, structure, a, k)
+    assert all("merge_spmm" in n for n in names), names
 
 
 @pytest.mark.parametrize("label,m,k", _mlp_shapes())
@@ -89,9 +98,10 @@ def test_rowsplit_compiles_for_v5e(one_chip, label, m, k):
     structure = jax.eval_shape(
         lambda a: rowsplit_spmm.plan_rowsplit_structure(a, l_pad=per_row),
         a)
-    _compile(lambda s, v, b: ops.rowsplit_execute(s, v, b, m=m,
-                                                   interpret=False),
-             one_chip, structure, a, k)
+    names = _compile(lambda s, v, b: ops.rowsplit_execute(s, v, b, m=m,
+                                                           interpret=False),
+                     one_chip, structure, a, k)
+    assert all("rowsplit_spmm" in n for n in names), names
 
 
 def test_rowgroup_compiles_for_v5e(one_chip):
@@ -103,6 +113,8 @@ def test_rowgroup_compiles_for_v5e(one_chip):
     fwd = dict(groups=(jax.eval_shape(
         lambda a: rowsplit_spmm.ell_slots(a, jnp.arange(m), per_row), a),),
         inv_pos=jax.ShapeDtypeStruct((m,), jnp.int32))
-    _compile(lambda s, v, b: rowgroup_spmm.rowgroup_execute_parts(
+    names = _compile(lambda s, v, b: rowgroup_spmm.rowgroup_execute_parts(
         groups, rowsplit_spmm.DEFAULT_TL, s, v, b, interpret=False),
         one_chip, fwd, a, k)
+    # Rowgroup launches the row-split kernel, once per length bucket.
+    assert all("rowsplit_spmm" in n for n in names), names

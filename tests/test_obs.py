@@ -1,19 +1,21 @@
-"""Observability subsystem: tracing, metrics, roofline, engine wiring.
+"""Observability subsystem: tracing, metrics, byte models, engine wiring.
 
-Covers the three legs of ``repro.obs`` (ring-buffer tracer + Chrome
-export, the typed metrics registry, the roofline accountant and its
-measured roof cache) and — more importantly — the *engine integration*:
+Covers the legs of ``repro.obs`` (ring-buffer tracer + Chrome export,
+profiler annotations, the typed metrics registry, the compulsory-bytes
+model; the scope vocabulary and its device-op join are in
+``test_scopes.py``) and — more importantly — the *engine integration*:
 ``PlanPolicy.resolve`` records which ladder rung fired, ``PlanCache``
 counts hits/misses/evictions through the registry (back-compat
-``stats()`` preserved), ``execute_plan`` emits dispatch events under
-tracing, and sharded builds trace the per-shard method mix + nnz
-imbalance.  The disabled path must be a no-op (shared null span, no
-events): the warm execute path pays one attribute read.
+``stats()`` preserved), plan builds time each step, ``execute_plan``
+emits dispatch events under tracing, and sharded builds trace the
+per-shard method mix + nnz imbalance.  With neither the ring nor a
+profiler recording, a span is the shared null span and records nothing.
 
 The sharded-trace tests need 8 devices; like ``test_distributed_spmm``
 they are re-run in a forced 8-device subprocess when the parent came up
 single-device, so they execute everywhere.
 """
+import glob
 import json
 import os
 import threading
@@ -30,7 +32,6 @@ from repro.engine import PlanCache
 from repro.matrices import compute_stats
 from repro.obs import validate as obs_validate
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.roofline import clear_roof_memo
 from repro.obs.trace import _NULL_SPAN
 from repro.tune.db import TuneDB, TuneRecord
 
@@ -233,6 +234,83 @@ def test_plan_cache_events_traced():
     assert tr.events(cat="plan", name="plan.build")  # span around build
 
 
+def _step_counts() -> dict:
+    fam = obs.registry.get("plan_build_step_seconds")
+    return {} if fam is None else {
+        (c.labels["method"], c.labels["step"]): c.count
+        for c in fam.children()}
+
+
+def _delta(before, after) -> dict:
+    return {k: n - before.get(k, 0) for k, n in after.items()
+            if n != before.get(k, 0)}
+
+
+def test_plan_build_steps_observed_once_and_nested():
+    cache = PlanCache(name="t-obs-steps")
+    a = _csr(15)
+    before = _step_counts()
+    with obs.tracing() as tr:
+        plan = cache.get(a, PlanPolicy())                # method "auto"
+    steps = ("fingerprint", "resolve", "structure", "row_ids")
+    method = plan.meta.method                 # the resolved method labels
+    assert method != "auto"                   # every step but the hash
+    assert _delta(before, _step_counts()) == {
+        ("" if s == "fingerprint" else method, s): 1 for s in steps}
+    build, = tr.events(cat="plan", name="plan.build")
+    end = build["ts"] + build["dur"]
+    span = {s: tr.events(cat="plan", name=f"plan.step.{s}") for s in steps}
+    assert all(len(v) == 1 for v in span.values())
+    # The hash keys the lookup and resolution picks the method, both
+    # before the build; the build's own steps nest inside it.
+    for s in ("fingerprint", "resolve"):
+        assert span[s][0]["ts"] + span[s][0]["dur"] <= build["ts"]
+    for s in ("structure", "row_ids"):
+        ev = span[s][0]
+        assert build["ts"] <= ev["ts"] and ev["ts"] + ev["dur"] <= end
+    fam = obs.registry.get("plan_build_step_seconds")
+    assert all(fam.labels(method="" if s == "fingerprint" else method,
+                          step=s).sum > 0 for s in steps)
+
+
+def test_plan_build_steps_always_on_and_only_in_builds():
+    assert not obs.is_enabled()
+    cache = PlanCache(name="t-obs-steps-off")
+    a = _csr(16)
+    before = _step_counts()
+    cache.get(a, PlanPolicy(method="merge"))             # a build
+    built = _step_counts()
+    assert _delta(before, built) == {
+        ("", "fingerprint"): 1, ("merge", "resolve"): 1,
+        ("merge", "structure"): 1, ("merge", "row_ids"): 1}
+    cache.get(a, PlanPolicy(method="merge"))             # a hit: nothing
+    assert _step_counts() == built
+    twin = type(a)(a.row_ptr, a.col_ind, a.vals, a.shape)
+    cache.get(twin, PlanPolicy(method="merge"))          # a new object:
+    assert _delta(built, _step_counts()) == {            # hashed, no build
+        ("", "fingerprint"): 1}
+
+
+def test_span_reaches_a_profiler_capture_with_the_ring_off(tmp_path):
+    from jax.profiler import ProfileData
+    assert not obs.is_enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("obs.test.annotated", cat="plan", m=1):
+            jax.block_until_ready(jax.numpy.ones(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert "obs.test.annotated" in host
+    tr = obs.get_tracer()
+    assert tr is None or not tr.events(name="obs.test.annotated")
+    # No profiler recording: back to the shared null span.
+    assert obs.span("obs.test.annotated") is _NULL_SPAN
+
+
 # ------------------------------------------------ resolve: ladder rungs ---
 
 
@@ -368,64 +446,17 @@ def test_plan_min_bytes_dtype_scaling():
                                       out_bytes=2)
 
 
-def test_accountant_math_and_report():
-    acc = obs.RooflineAccountant()
-    # 10 calls totaling 1000 us, 1 MB/call: 10 MB / 1e-3 s = 1e10 B/s
-    acc.record(("spmm", "merge", "xla", "float32"), wall_us=1000.0,
-               min_bytes=10e6, flops=2e6, calls=10)
-    roof = obs.Roof(backend="cpu", bytes_per_s=2e10, elements=1,
-                    source="measured")
-    row, = acc.rows(roof)
-    assert row["achieved_bytes_per_s"] == pytest.approx(1e10)
-    assert row["roof_fraction"] == pytest.approx(0.5)
-    assert row["gflops_per_s"] == pytest.approx(2.0)
-    text = acc.report(roof)
-    assert "50.0% of roof" in text and "merge/xla" in text
-    acc.reset()
-    assert len(acc) == 0
-    assert "no executions" in acc.report()
-
-
-def test_accountant_account_plan_uses_model():
-    acc = obs.RooflineAccountant()
-    a = _csr(13)
-    plan = build_plan(a, method="rowsplit", with_transpose=False)
-    acc.account_plan(plan.meta, 16, wall_us=100.0, impl="xla", calls=4)
-    row, = acc.rows()
-    assert row["method"] == "rowsplit" and row["calls"] == 4
-    assert row["min_bytes"] == 4 * obs.plan_min_bytes(plan.meta, 16)
-
-
-def test_measure_roof_file_cache(tmp_path):
-    clear_roof_memo()
-    cache = str(tmp_path / "arts")
-    r1 = obs.measure_roof(cache_dir=cache, elements=1 << 12, repeat=1)
-    assert r1.source == "measured" and r1.bytes_per_s > 0
-    assert os.path.exists(os.path.join(cache, "roofline_roof.json"))
-    clear_roof_memo()                  # drop the in-process memo
-    r2 = obs.measure_roof(cache_dir=cache, elements=1 << 12, repeat=1)
-    assert r2.source == "cached"
-    assert r2.bytes_per_s == pytest.approx(r1.bytes_per_s)
-    r3 = obs.measure_roof(cache_dir=cache, force=True, elements=1 << 12,
-                          repeat=1)
-    assert r3.source == "measured"
-    clear_roof_memo()
-
-
 def test_obs_report_combines_legs():
     a = _csr(14)
     PlanPolicy(method="merge").resolve(a)
-    plan = build_plan(a, method="merge", with_transpose=False)
-    obs.accountant.account_plan(plan.meta, 8, wall_us=50.0, impl="xla")
-    roof = obs.Roof(backend="cpu", bytes_per_s=1e10, elements=1,
-                    source="cached")
-    try:
-        text = obs.report(roof=roof)
-        assert "resolution ladder" in text and "explicit=" in text
-        assert "plan_resolve_total{rung=explicit,method=merge}" in text
-        assert "% of roof" in text and "merge/xla" in text
-    finally:
-        obs.accountant.reset()
+    with obs.tracing() as tr:
+        obs.event("tick")
+        text = obs.report()
+    assert "resolution ladder" in text and "explicit=" in text
+    assert "plan_resolve_total{rung=explicit,method=merge}" in text
+    assert "== trace == 1 events buffered" in text
+    assert len(tr) == 1
+    assert "roof" not in text          # no second roofline beside bench/
 
 
 # ------------------------------------------------------------- validate ---
