@@ -41,16 +41,21 @@ from collections.abc import Callable
 
 import numpy as np
 
+from repro.kernels import merge_spmm as _ms
 from repro.kernels.introspect import KernelBlock, KernelLaunch
 
 from .diagnostics import Diagnostic
 
-#: Static on-chip memory budgets per backend, bytes: a TPU v5e core has
-#: 16 MiB of VMEM and 1 MiB of SMEM (the v5e compiler refuses a kernel
-#: whose prefetched scalars exceed 1 MiB).  The audit models the TPU
-#: target — the CPU interpret substrate has no such limit but must not
-#: mask a lowering that could never fit real hardware.
-VMEM_BUDGET_BYTES = {"tpu": 16 * 2 ** 20}
+#: Static on-chip memory budgets per backend, bytes.  VMEM: the most a
+#: launch's modeled blocks may take, which is the largest scoped limit the
+#: kernels request (``merge_spmm.vmem_limit``: the blocks plus a headroom,
+#: at most the VMEM) less that headroom — 124 MiB of a v5e TensorCore's
+#: 128 MiB (16 MiB is Mosaic's default scoped limit, not the VMEM).  SMEM:
+#: 1 MiB (the v5e compiler refuses a kernel whose prefetched scalars
+#: exceed 1 MiB).  The audit models the TPU target — the CPU interpret
+#: substrate has no such limit but must not mask a lowering that could
+#: never fit real hardware.
+VMEM_BUDGET_BYTES = {"tpu": _ms.V5E_VMEM_BYTES - _ms.VMEM_HEADROOM_BYTES}
 SMEM_BUDGET_BYTES = {"tpu": 2 ** 20}
 
 AUDIT_IMPLS = ("pallas", "xla")
@@ -342,12 +347,13 @@ def scale_rows(*, k: int = 29568) -> list[str]:
     """Informational serving-scale probe lines for the report (the
     representative audit proves the invariants; this states where the
     static models say the current lowering stops scaling)."""
-    from repro.kernels.merge_spmm import T_MAX, resolve_tk
+    from repro.kernels.merge_spmm import T_MAX, resolve_tk, tk_max
     tk, n_k = resolve_tk(k, None)
     chunks = merge_chunk_ceiling()
     return [
         f"scale probe: k={k} resolves to tk={tk} ({n_k} K-tiles) — the "
-        f"B panel stays {tk * 128 * 4 // 1024} KiB/buffer at any d_in",
+        f"B panel takes {tk * 128 * 4 / 2 ** 20:.1f} MiB/buffer; a d_in "
+        f"above {tk_max():,} streams in panels of that many rows",
         f"scale probe: merge's prefetched chunk streams cap a launch at "
         f"{chunks:,} chunks (~{chunks * T_MAX:,} nonzeros at T={T_MAX}) "
         "of the 1 MiB SMEM",

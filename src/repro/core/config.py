@@ -292,8 +292,10 @@ class ExecutionConfig:
     ``impl``: ``"pallas"`` (the TPU kernels; interpret mode on CPU) or
     ``"xla"`` (the pure-XLA twins).  ``interpret``: force Pallas interpret
     mode (None: auto — interpret off TPU).  ``tk``: cap the K-tile of the
-    streamed B panel (None: whole ``k`` up to
-    ``kernels.merge_spmm.DEFAULT_TK_MAX``).
+    B panel (None: the whole ``k`` resident in VMEM up to
+    ``kernels.merge_spmm.tk_max()``, 98,304 rows on a v5e's 128 MiB of
+    VMEM; the 16 MiB that once capped it at 1024 rows was Mosaic's default
+    scoped limit, not the VMEM).
 
     ``epilogue``: a fused :class:`~repro.core.epilogue.Epilogue` spec —
     ``y = act(C + bias) * scale + residual`` applied at the kernels'

@@ -41,12 +41,14 @@ Two grid axes beyond the paper's decomposition:
   ``B (batch, k, n)`` in a single dispatch — the plan-once/execute-many
   serving regime with the batch folded into the grid instead of a Python
   loop of launches.
-* **k_tiles** (innermost): the dense operand streams through VMEM in
-  ``(TK, TN)`` panels with the accumulator carried across tiles, so VMEM
-  stays bounded at any ``k`` (``d_in``) instead of pinning the whole
-  ``(k, TN)`` panel.  Column indices outside the resident panel are masked
-  per tile; when ``k <= DEFAULT_TK_MAX`` a single tile covers all of ``k``
-  and the dataflow (and bit pattern) is exactly the unsplit kernel's.
+* **k_tiles** (innermost): the dense operand sits in VMEM as one whole
+  ``(k, TN)`` panel whenever two float32 copies of it fit the panel's
+  share of the TensorCore's VMEM (``tk_max``: every ``k`` up to 98,304 on
+  a v5e), so each nonzero costs one scalar trip and B is fetched once per
+  lane tile.  A longer ``k`` (or an explicit ``tk``) streams through
+  ``(TK, TN)`` panels with the accumulator carried across tiles and the
+  column indices outside the resident panel masked per tile, at one trip
+  per nonzero per tile.
 
 Latency hiding: the paper's ILP (32 independent loads per thread) becomes
 ``SLOT_UNROLL`` independent row loads per loop trip plus Mosaic's
@@ -63,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core.csr import CSR, rows_from_row_ptr
 from repro.core.epilogue import apply_epilogue
 from repro.obs import scope
@@ -78,27 +81,109 @@ T_MAX = 1024
 # Nonzeroes per trip of the kernels' scalar slot loops (``fold_slots``):
 # the independent row loads in flight, the paper's per-thread ILP.
 SLOT_UNROLL = 8
-# K-tile cap: the B panel streams through VMEM in (TK, TN) blocks.  At the
-# default, a float32 panel is 1024*128*4 = 512 KiB per buffer (~1 MiB double
-# buffered) — bounded regardless of d_in, where the old whole-(k, TN) panel
-# hit 4 MiB at k=8k and overflowed VMEM entirely at Qwen2-72B's d_in=29568.
-DEFAULT_TK_MAX = 1024
+# VMEM sizing.  The B panel is a float32 (TK, TN) block, double-buffered by
+# the Mosaic pipeline; its two buffers may take PANEL_SHARE of the
+# TensorCore's VMEM, which leaves the rest for the out, acc, bias and
+# residual blocks and Mosaic's own scratch.  Off a TPU the v5e's 128 MiB
+# stands in, so interpret mode and the static analyzers model the chip the
+# benchmark runs on.  A launch whose blocks, with VMEM_HEADROOM_BYTES to
+# spare, outgrow Mosaic's default scoped limit (16 MiB on a v5e, not the
+# VMEM) asks for a larger one (``vmem_limit``).
+V5E_VMEM_BYTES = 128 * 2 ** 20
+PANEL_SHARE = (3, 4)
+DEFAULT_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+VMEM_HEADROOM_BYTES = 4 * 2 ** 20
+
+
+def vmem_capacity_bytes() -> int:
+    """The TensorCore's VMEM: read from the chip on a TPU backend, else
+    the v5e's."""
+    if jax.default_backend() != "tpu":
+        return V5E_VMEM_BYTES
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def panel_rows(vmem_bytes: int, *, sub: int = 8) -> int:
+    """Most rows (a sublane multiple) of a float32 ``(rows, TN)`` panel
+    whose two buffers fit ``PANEL_SHARE`` of ``vmem_bytes``."""
+    num, den = PANEL_SHARE
+    return vmem_bytes * num // den // (2 * TN * 4) // sub * sub
+
+
+# K-tile cap on a v5e: 96 MiB of its 128 MiB VMEM holds two 98,304-row
+# panels, so every d_in of ``repro.configs`` (Qwen2-72B's 29,568: 14.4 MiB a
+# buffer) and the graph benchmark's 65,536 runs as one K-tile.
+DEFAULT_TK_MAX = panel_rows(V5E_VMEM_BYTES)
+
+
+def tk_max() -> int:
+    """The K-tile cap of the chip this process compiles for."""
+    return panel_rows(vmem_capacity_bytes())
 
 
 def resolve_tk(k: int, tk: int | None, *, sub: int = 8) -> tuple[int, int]:
     """Resolve the K-tile size: returns ``(tk, n_k)``.
 
     ``tk`` is clamped to a sublane multiple and to the (padded) ``k``;
-    ``None`` picks the whole of ``k`` up to ``DEFAULT_TK_MAX``, so small
-    operands keep the single-panel dataflow bit-for-bit while large ``k``
-    streams in bounded panels.
+    ``None`` picks the whole of ``k`` up to ``tk_max()``, so the panel stays
+    resident (one K-tile, one trip per nonzero) wherever VMEM holds it and
+    a larger ``k`` streams in panels of the cap.
     """
     k_pad = max(sub, sub * (-(-k // sub)))
     if tk is None:
-        tk = min(k_pad, DEFAULT_TK_MAX)
+        tk = min(k_pad, tk_max())
     else:
         tk = min(max(sub, sub * (-(-tk // sub))), k_pad)
     return tk, -(-k_pad // tk)
+
+
+def launch_vmem_bytes(tk: int, *, tm: int, tn: int, out_dtype, acc_dtype,
+                      bias_dtype=None, residual_dtype=None) -> int:
+    """Modeled VMEM of one SpMM launch: the float32 B panel, out, bias and
+    residual blocks double-buffered and the accumulator once — the blocks
+    the static launch models (``launch_models``, ``ell_launch``) count."""
+    size = lambda shape, dt: int(np.prod(shape)) * jnp.dtype(dt).itemsize
+    total = 2 * size((tk, tn), jnp.float32) + 2 * size((tm, tn), out_dtype)
+    total += size((tm, tn), acc_dtype)
+    if bias_dtype is not None:
+        total += 2 * size((tm, 1), bias_dtype)
+    if residual_dtype is not None:
+        total += 2 * size((tm, tn), residual_dtype)
+    return total
+
+
+def vmem_limit(blocks_bytes: int) -> int | None:
+    """The scoped VMEM limit a launch asks for: None (Mosaic's default)
+    while its blocks fit that with the headroom, else the blocks plus the
+    headroom, at most the VMEM."""
+    need = blocks_bytes + VMEM_HEADROOM_BYTES
+    if need <= DEFAULT_SCOPED_VMEM_BYTES:
+        return None
+    return min(need, vmem_capacity_bytes())
+
+
+# Kernel launches by K-tile count, counted where a launch is traced (a jit
+# cache hit, such as a second launch of one shape in a program, is not a
+# trace), never per call: a jitted program adds nothing to a timed window.
+_k_tiles_total = obs.registry.counter(
+    "spmm_kernel_k_tiles_total",
+    "SpMM kernel launches traced, by kernel and K-tile count",
+    labels=("kernel", "k_tiles"))
+
+
+def launch_params(kernel: str, tk: int, n_k: int, *, tm: int, tn: int,
+                  out_dtype, acc_dtype, ep, bias, residual):
+    """Count a traced launch of ``kernel`` in ``spmm_kernel_k_tiles_total``
+    and return its ``compiler_params``: a raised scoped VMEM limit where
+    its blocks need one, else None."""
+    _k_tiles_total.labels(kernel=kernel, k_tiles=str(n_k)).inc()
+    limit = vmem_limit(launch_vmem_bytes(
+        tk, tm=tm, tn=tn, out_dtype=out_dtype, acc_dtype=acc_dtype,
+        bias_dtype=bias.dtype if ep is not None and ep.bias else None,
+        residual_dtype=residual.dtype if ep is not None and ep.residual
+        else None))
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 def default_t(m: int, nnz_pad: int, *, tm: int = TM) -> int:
@@ -336,7 +421,8 @@ def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
     (default: b's dtype).
 
     Returns (batch, m_pad, n): the batch rides the leading grid axis (one
-    dispatch for the whole stack) and B streams in (TK, TN) VMEM panels.
+    dispatch for the whole stack) and B sits in VMEM as one whole-``k``
+    panel up to ``tk_max()`` rows, else streams in (TK, TN) panels.
     Each grid step DMAs only its own chunk of indices and values into
     SMEM; the chunk-to-tile stream, the first/last flags and the per-chunk
     slot counts are scalar-prefetched (16 bytes a chunk of the 1 MiB
@@ -382,11 +468,15 @@ def merge_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array,
     )
     kernel = functools.partial(_merge_kernel, tk=tk, n_k=n_k,
                                acc_dtype=acc_dtype, ep=ep)
+    params = launch_params("merge_spmm", tk, n_k, tm=tm, tn=tn,
+                           out_dtype=out_dtype, acc_dtype=acc_dtype, ep=ep,
+                           bias=bias, residual=residual)
     with scope("kernel"):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
+            compiler_params=params,
             interpret=interpret,
             name="merge_spmm",
         )(plan["tile"], plan["first"], plan["last"], count, *operands)

@@ -4,11 +4,11 @@ TPU adaptation of the paper's warp-per-row kernel:
 
 * The GPU warp's 32 lanes reading 32 consecutive floats of a row-major B row
   become a ``TN=128``-lane row slice of B read from a VMEM-resident
-  ``(TK, TN)`` panel — the dense operand streams through VMEM in K tiles
-  with the accumulator carried across them (grid axis ``k_tiles``,
-  innermost), so VMEM stays bounded at any ``k``; a leading ``batch`` grid
-  axis executes a whole stack of dense operands per dispatch (see
-  ``merge_spmm`` for the shared rationale).
+  ``(TK, TN)`` panel — the whole ``(k, TN)`` panel wherever VMEM holds it,
+  else K tiles streamed with the accumulator carried across them (grid
+  axis ``k_tiles``, innermost); a leading ``batch`` grid axis executes a
+  whole stack of dense operands per dispatch (see ``merge_spmm`` for the
+  shared rationale).
 * "Equal rows per processor" becomes a grid over ``TM``-row tiles of C; each
   row is ELL-padded to the static bound ``L`` (the longest row) and walked
   in windows of up to ``L_WINDOW`` slots per grid step — the TPU
@@ -40,7 +40,8 @@ from repro.core.csr import CSR
 from repro.obs import scope
 
 from .merge_spmm import (TM, TN, apply_vals, flush_tile, fold_slots,
-                         resolve_tk, scaled_b_row, smem_vals, split_refs)
+                         launch_params, resolve_tk, scaled_b_row, smem_vals,
+                         split_refs)
 
 DEFAULT_TL = 16
 # Most ELL slots per grid step: a (TM, L_WINDOW) int32 index block and its
@@ -166,10 +167,11 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
     ``acc_dtype``/``out_dtype`` control accumulation and output precision
     (see ``merge_spmm_pallas``).
 
-    Returns (batch, m_pad, n): batch on the leading grid axis, B streamed
-    through VMEM in (TK, TN) panels (``k_tiles`` innermost, accumulator
-    carried).  Each step DMAs a ``(TM, window)`` block of column indices
-    and values into SMEM; the per-row lengths are scalar-prefetched.
+    Returns (batch, m_pad, n): batch on the leading grid axis, B resident
+    in VMEM as one whole-``k`` panel up to ``tk_max()`` rows, else streamed
+    in (TK, TN) panels (``k_tiles`` innermost, accumulator carried).  Each
+    step DMAs a ``(TM, window)`` block of column indices and values into
+    SMEM; the per-row lengths are scalar-prefetched.
     """
     out_dtype = b.dtype if out_dtype is None else out_dtype
     with scope("widen_b"):
@@ -208,6 +210,9 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
         operands.append(residual)
     kernel = functools.partial(_rowsplit_kernel, tm=tm, tw=tw, tk=tk,
                                n_k=n_k, acc_dtype=acc_dtype, ep=ep)
+    params = launch_params("rowsplit_spmm", tk, n_k, tm=tm, tn=tn,
+                           out_dtype=out_dtype, acc_dtype=acc_dtype, ep=ep,
+                           bias=bias, residual=residual)
     with scope("kernel"):
         return pl.pallas_call(
             kernel,
@@ -220,6 +225,7 @@ def rowsplit_spmm_pallas(plan: dict, vals: jax.Array, b: jax.Array, *,
                                        (bb, i, j)),
                 scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)]),
             out_shape=jax.ShapeDtypeStruct((batch, m_pad, n), out_dtype),
+            compiler_params=params,
             interpret=interpret,
             name="rowsplit_spmm",
         )(lens, *operands)

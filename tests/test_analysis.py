@@ -427,10 +427,10 @@ def test_audit_vmem_budget_flags_blowup():
                                              LaunchModel, merge_chunk_ceiling,
                                              merge_smem_bytes)
     from repro.kernels.merge_spmm import T_MAX
-    # A 16 MiB block, double-buffered, overflows the 16 MiB VMEM model and
-    # counts nothing against SMEM.
-    big = Block("b", (4096, 1024), "float32", lambda i: (0, 0),
-                (4096, 1024), "in")
+    # A 64 MiB block, double-buffered, overflows the VMEM model (the 128
+    # MiB VMEM less the kernels' headroom) and counts nothing against SMEM.
+    big = Block("b", (16384, 1024), "float32", lambda i: (0, 0),
+                (16384, 1024), "in")
     model = LaunchModel("big", grid=(1,), blocks=(big,),
                         flush=lambda i: True, out=big)
     assert model.vmem_bytes() > VMEM_BUDGET_BYTES["tpu"]

@@ -16,7 +16,9 @@ import pytest
 import repro.models.sparse as S
 from repro.core import (CSR, ExecutionConfig, PlanPolicy, build_plan,
                         execute_plan, random_csr, spmm)
+from repro.kernels import merge_spmm as M
 from repro.kernels import ref
+from repro.kernels.merge_spmm import resolve_tk
 
 EC = ExecutionConfig  # keep call sites within the line limit
 
@@ -223,13 +225,101 @@ def test_ktile_stream_matches_oracle(method, tk):
 
 
 def test_resolve_tk_bounds_vmem():
-    from repro.kernels.merge_spmm import DEFAULT_TK_MAX, resolve_tk
     assert resolve_tk(64, None) == (64, 1)
     assert resolve_tk(65, None) == (72, 1)           # sublane-padded
-    tk, n_k = resolve_tk(29568, None)                # Qwen2-72B d_in
-    assert tk == DEFAULT_TK_MAX and n_k * tk >= 29568
+    assert resolve_tk(29568, None) == (29568, 1)     # Qwen2-72B d_in
+    assert resolve_tk(65536, None) == (65536, 1)     # the graph benchmark
+    # Above the cap the panel still streams, in cap-sized K-tiles.
+    tk, n_k = resolve_tk(M.DEFAULT_TK_MAX + 1, None)
+    assert (tk, n_k) == (M.DEFAULT_TK_MAX, 2)
     assert resolve_tk(100, 16) == (16, 7)
     assert resolve_tk(100, 3) == (8, 13)             # sublane floor
+    # Two float32 panel buffers stay within the panel's share of VMEM, and
+    # the largest launch's limit, headroom included, within the VMEM.
+    num, den = M.PANEL_SHARE
+    assert 2 * M.DEFAULT_TK_MAX * M.TN * 4 <= M.V5E_VMEM_BYTES * num // den
+    widest = M.launch_vmem_bytes(
+        M.DEFAULT_TK_MAX, tm=M.TM, tn=M.TN, out_dtype=jnp.float32,
+        acc_dtype=jnp.float32, bias_dtype=jnp.float32,
+        residual_dtype=jnp.float32)
+    assert widest + M.VMEM_HEADROOM_BYTES <= M.V5E_VMEM_BYTES
+
+
+def test_vmem_limit_only_above_default_scope():
+    """A launch asks for more scoped VMEM only when its blocks outgrow the
+    default: the granite w2 panel (k=8192) does not, the graph's does."""
+
+    def limit(k):
+        tk, _ = resolve_tk(k, None)
+        return M.vmem_limit(M.launch_vmem_bytes(
+            tk, tm=M.TM, tn=M.TN, out_dtype=jnp.float32,
+            acc_dtype=jnp.float32))
+
+    assert limit(8192) is None
+    got = limit(65536)
+    assert 2 * 65536 * M.TN * 4 + M.VMEM_HEADROOM_BYTES <= got
+    assert got <= M.V5E_VMEM_BYTES
+
+
+@pytest.mark.parametrize("method", METHODS)
+def test_launch_vmem_matches_static_model(method):
+    """The VMEM a kernel sizes its limit from is the static launch model's,
+    for every audited dtype/epilogue variant."""
+    from repro.analysis.kernel_audit import _variants
+    from repro.kernels.registry import get_method
+    a = random_csr(jax.random.PRNGKey(5), 40, 4096, nnz_per_row=(0, 6))
+    plan = build_plan(a, method=method)
+    for var in _variants():
+        (model,) = get_method(method).traffic(plan, 128, 1, var, None)
+        ep = var.epilogue
+        got = M.launch_vmem_bytes(
+            resolve_tk(a.k, None)[0], tm=M.TM, tn=M.TN,
+            out_dtype=var.out_dtype or var.b_dtype,
+            acc_dtype=var.acc_dtype,
+            bias_dtype=var.b_dtype if ep is not None and ep.bias else None,
+            residual_dtype=var.b_dtype if ep is not None and ep.residual
+            else None)
+        assert got == model.vmem_bytes(), var.name
+
+
+@pytest.mark.parametrize("method", METHODS)
+def test_whole_k_panel_matches_ktiled_and_oracle(method):
+    """k=4096 now runs as one resident panel by default; it matches the
+    dense oracle and the forced 1024-row K-tiled path (four tiles)."""
+    a = random_csr(jax.random.PRNGKey(13), 24, 4096, nnz_per_row=(0, 40))
+    b = jax.random.normal(jax.random.PRNGKey(14), (4096, 128))
+    plan = build_plan(a, method=method, with_transpose=False)
+    assert resolve_tk(a.k, None) == (4096, 1)
+    o_whole = execute_plan(plan, a.vals, b, EC(impl="pallas"))
+    o_tiled = execute_plan(plan, a.vals, b, EC(impl="pallas", tk=1024))
+    want = np.asarray(a.to_dense()) @ np.asarray(b)
+    np.testing.assert_allclose(np.asarray(o_whole), want, **TOL)
+    np.testing.assert_allclose(np.asarray(o_whole), np.asarray(o_tiled),
+                               **TOL)
+
+
+@pytest.mark.parametrize("method", METHODS)
+def test_k_tiles_counter_counts_traces(method):
+    """``spmm_kernel_k_tiles_total`` counts a launch once when it is traced,
+    labelled with its K-tile count, and never again per call."""
+    from repro import obs
+    kernel = f"{method}_spmm"
+    fam = obs.registry.get("spmm_kernel_k_tiles_total")
+    count = lambda n_k: fam.labels(kernel=kernel, k_tiles=n_k).value
+    a = random_csr(jax.random.PRNGKey(15), 24, 200, nnz_per_row=(0, 9))
+    b = jax.random.normal(jax.random.PRNGKey(16), (200, 128))
+    plan = build_plan(a, method=method, with_transpose=False)
+    before = {n_k: count(n_k) for n_k in ("1", "25")}
+    fwd = jax.jit(lambda v, b: execute_plan(plan, v, b, EC(impl="pallas")))
+    tiled = jax.jit(lambda v, b: execute_plan(plan, v, b,
+                                              EC(impl="pallas", tk=8)))
+    fwd(a.vals, b).block_until_ready()
+    tiled(a.vals, b).block_until_ready()
+    after = {n_k: count(n_k) for n_k in ("1", "25")}
+    assert after == {"1": before["1"] + 1, "25": before["25"] + 1}
+    fwd(a.vals, b).block_until_ready()
+    tiled(a.vals * 2, b).block_until_ready()
+    assert {n_k: count(n_k) for n_k in ("1", "25")} == after
 
 
 # --------------------------------------------------- degenerate patterns ---

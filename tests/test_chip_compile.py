@@ -4,7 +4,8 @@ Interpret mode runs the kernel bodies in Python on the CPU and never asks
 Mosaic (the TPU kernel compiler) whether a block shape, a gather or an
 on-chip footprint is legal.  These tests do: each compiles one forward
 kernel for a described (not attached) v5e chip at a llama3.2-1b MLP shape
-with 25% of the weights kept, and checks that the compiled program holds
+with 25% of the weights kept, or at a benchmark cell's shape with its
+whole B panel resident in VMEM, and checks that the compiled program holds
 the kernel as a ``tpu_custom_call`` named after the kernel's ``name=``
 (what the benchmark's trace reduction finds it by).  Nothing runs, so
 this says nothing about results or speed.
@@ -73,6 +74,10 @@ def _compile(fn, sharding, structure, a, k):
     b = jax.ShapeDtypeStruct((1, k, TOKENS), jnp.float32)
     compiled = jax.jit(fn).lower(*_on(sharding, (structure, a.vals, b))) \
         .compile()
+    return _kernel_names(compiled)
+
+
+def _kernel_names(compiled):
     names = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*"
                        r'custom_call_target="tpu_custom_call"',
                        compiled.as_text(), re.M)
@@ -118,3 +123,57 @@ def test_rowgroup_compiles_for_v5e(one_chip):
         one_chip, fwd, a, k)
     # Rowgroup launches the row-split kernel, once per length bucket.
     assert all("rowsplit_spmm" in n for n in names), names
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+# (m, k, nonzeros) of the benchmark cells' SpMM launches: the Graph 500
+# SCALE 16 adjacency (graph500-s16.agg-f128) and granite-3-2b's w2 at 25%
+# a row (granite-3-2b.score-128), whose k the 1024-row cap used to split
+# into 64 and 8 K-tiles.
+CELLS = {
+    "graph500-s16 merge": ("merge", 65536, 65536, 1819722),
+    "granite-3-2b w2 rowsplit": ("rowsplit", 2048, 8192, 2048 * 2048),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_kernel_compiles_with_one_k_tile(one_chip, cell):
+    """The cell's kernel holds its whole B panel (one K-tile) and compiles
+    for the v5e: the graph's 2 x 32 MiB panel only with the raised scoped
+    VMEM limit it asks for, granite's 2 x 4 MiB within the default."""
+    method, m, k, nnz = CELLS[cell]
+    a = CSR(jax.ShapeDtypeStruct((m + 1,), jnp.int32),
+            jax.ShapeDtypeStruct((nnz,), jnp.int32),
+            jax.ShapeDtypeStruct((nnz,), jnp.float32), (m, k))
+    if method == "merge":
+        t = merge_spmm.default_t(m, nnz)
+        structure = jax.eval_shape(
+            lambda a: merge_spmm.plan_merge_structure(a, t=t), a)
+        fn = lambda s, v, b: ops.merge_execute(s, v, b, m=m,
+                                               interpret=False)
+    else:
+        structure = jax.eval_shape(
+            lambda a: rowsplit_spmm.plan_rowsplit_structure(
+                a, l_pad=nnz // m), a)
+        fn = lambda s, v, b: ops.rowsplit_execute(s, v, b, m=m,
+                                                  interpret=False)
+    args = _on(one_chip, (structure, a.vals,
+                          jax.ShapeDtypeStruct((1, k, TOKENS), jnp.float32)))
+    (call,) = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert call.params["grid_mapping"].grid[-1] == 1       # one K-tile
+    limits = [p.vmem_limit_bytes
+              for p in call.params["compiler_params"].values()]
+    assert bool(limits) == (k == 65536), limits
+    names = _kernel_names(jax.jit(fn).lower(*args).compile())
+    assert all(f"{method}_spmm" in n for n in names), names
