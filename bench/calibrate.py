@@ -44,7 +44,8 @@ def main(argv=None) -> int:
     for j in range(args.seeds):
         seed = args.first_seed + 7919 * j
         t0 = time.perf_counter()
-        system = cls(cell.config, cell.traffic, seed, harness.SetupClock())
+        system = cls(cell.config, cell.traffic, seed, harness.SetupClock(),
+                     devs)
         system.setup()
         sampler = harness.Sampler(int(cell.traffic["sample"]), seed)
         window = loop(system, args.seconds, sampler)

@@ -4,6 +4,8 @@ Everything that belongs to one cell, configuration, traffic mix or
 metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
 
     bench/configs/<config>.json      sizes; ``family`` names the system
+    bench/tests/tiny/<config>.json   the sizes that replace the
+                                     configuration's in the CPU tests
     bench/systems/<family>.py        builds the system under test from the
                                      program, drives its timed path, and
                                      compares with the reference
@@ -12,6 +14,13 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
     bench/limits/<cell>.json         limits that decide ``correct``
     bench/metrics/<metric>.py        ``read(run)`` -> number or None
     bench/peaks.json                 published peaks by ``device_kind``
+
+A system is ``System(config, traffic, seed, clock, devices)``: ``devices``
+are the chips found for its cell, as many as the cell's ``chips``, and
+the system keeps its state on them and on no other (a one-chip system on
+``devices[0]``; a multi-chip one builds its mesh from them).  Its
+``unit_work`` counts the work of a whole call once, whatever the number
+of chips; the readers compare it with the peak of all the cell's chips.
 
 A run: find the chips (no TPU, or fewer than the cell asks for: exit
 non-zero with no result), set up (weights or graph from the seed, the
@@ -215,6 +224,17 @@ def _annotate(name):
     return jax.profiler.TraceAnnotation(name)
 
 
+def _complete(w: Window, system, i: int, out, sampler: Sampler) -> int:
+    """Counts call ``i``'s work in the window and offers its answer to the
+    check; returns its tokens."""
+    n = system.unit_tokens(i)
+    wk = system.unit_work(i)
+    w.flops += wk["flops"]
+    w.spmm.extend(wk["spmm"])
+    sampler.offer(n or 0, (i, out))
+    return n or 0
+
+
 def closed_batch(system, seconds: float, sampler: Sampler) -> Window:
     """One call at a time, each sent when the last has finished; every
     call's work and time counts, the last one ending after the close."""
@@ -229,12 +249,7 @@ def closed_batch(system, seconds: float, sampler: Sampler) -> Window:
                 out = system.call(i)
             with _annotate("bench.wait"):
                 out = jax.block_until_ready(out)
-            n = system.unit_tokens(i)
-            wk = system.unit_work(i)
-            w.flops += wk["flops"]
-            w.spmm.extend(wk["spmm"])
-            tokens += n or 0
-            sampler.offer(n or 0, (i, out))
+            tokens += _complete(w, system, i, out, sampler)
             del out
             i += 1
             if time.perf_counter() >= deadline:
@@ -245,7 +260,59 @@ def closed_batch(system, seconds: float, sampler: Sampler) -> Window:
     return w
 
 
-LOOPS = {"closed_batch": closed_batch}
+# Seconds of calls the pipelined loop keeps dispatched ahead of the one it
+# waits for: the chip stays fed while the host stands still for that long,
+# and the wait after the close ends within it.
+AHEAD_S = 8.0
+
+
+def pipelined(system, seconds: float, sampler: Sampler) -> Window:
+    """Calls back to back, in order, with as many dispatched ahead of the
+    one waited for as the rate completed so far gets through in
+    ``AHEAD_S`` (one until the first is back).  Answers already back are
+    taken as soon as they are seen, so only calls still in flight hold
+    their outputs.  When the time is up nothing more is sent: every call
+    sent is waited for and counts, and the window closes after the last
+    of them."""
+    import collections
+
+    import jax
+    tokens = 0
+    sent = collections.deque()
+    with _annotate("bench.window"):
+        w = Window(time.perf_counter(), 0.0)
+        deadline = w.t0 + seconds
+        i = done = 0
+
+        def oldest_back() -> bool:
+            return all(getattr(x, "is_ready", lambda: True)()
+                       for x in jax.tree.leaves(sent[0][1]))
+
+        def wait_oldest():
+            nonlocal tokens, done
+            j, out = sent.popleft()
+            with _annotate("bench.wait"):
+                out = jax.block_until_ready(out)
+            tokens += _complete(w, system, j, out, sampler)
+            done += 1
+
+        while time.perf_counter() < deadline:
+            with _annotate("bench.dispatch"):
+                sent.append((i, system.call(i)))
+            i += 1
+            ahead = max(1, int(AHEAD_S * done /
+                               (time.perf_counter() - w.t0)))
+            while sent and (len(sent) > ahead or oldest_back()):
+                wait_oldest()
+        while sent:
+            wait_oldest()
+        w.t_end = time.perf_counter()
+    w.units = w.attempted = i
+    w.tokens = tokens if system.unit_tokens(0) is not None else None
+    return w
+
+
+LOOPS = {"closed_batch": closed_batch, "pipelined": pipelined}
 
 
 # --------------------------------------------------------------- the run ---
@@ -260,6 +327,7 @@ class Run:
     t_start: float                   # when the chips were found
     setup_phases: dict
     window: Window
+    chips: int                       # the cell's chips, whose peaks add
     trace: object = None             # bench.trace_reduce.Trace
 
     @property
@@ -296,7 +364,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         prepare_jax(cell.root)
         cls = system_class(cell)
         from repro import engine
-    system = cls(cell.config, cell.traffic, seed, clock)
+    system = cls(cell.config, cell.traffic, seed, clock, devs)
     system.setup()
     sampler = Sampler(int(cell.traffic["sample"]), seed)
     counter = CompileCounter.get()
@@ -321,7 +389,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     mem = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
               for d in devs)
     run = Run(cell=cell, system=system, peak=peak, t_start=t_start,
-              setup_phases=dict(clock.phases), window=window)
+              setup_phases=dict(clock.phases), window=window,
+              chips=len(devs))
     device = {"platform": devs[0].platform, "kind": kind,
               "count": len(devs), "memory_peak_bytes": mem}
     if trace:

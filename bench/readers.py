@@ -18,8 +18,9 @@ def is_spmm_kernel(op) -> bool:
 
 
 def spmm_roofline(run):
-    """Percent: least time of the window's SpMM calls over the summed
-    device time of the SpMM kernel events; None without a trace or
+    """Percent: least time of the window's SpMM calls on all the cell's
+    chips together (one chip's least time over the chip count) over the
+    SpMM kernel events' device time a chip; None without a trace or
     without kernel events."""
     calls = run.window.spmm
     if run.trace is None or run.peak is None or not calls:
@@ -27,7 +28,8 @@ def spmm_roofline(run):
     kernel_s = run.trace.op_seconds(is_spmm_kernel)
     if kernel_s <= 0:
         return None
-    return 100.0 * work.spmm_least_seconds(calls, run.peak) / kernel_s
+    least = work.spmm_least_seconds(calls, run.peak) / run.chips
+    return 100.0 * least / kernel_s
 
 
 def idle_share(run):

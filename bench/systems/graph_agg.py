@@ -14,7 +14,8 @@ nonzero in both directions, self-loops are dropped and repeated edges
 keep their lightest weight.  The program builds its plan
 (``SparseMatrix.from_csr`` with the configuration's ``method``),
 compiles ``A @ X`` at the traffic's feature width and warms it.  The
-window multiplies a small pool of seeded feature matrices in turn.
+window multiplies a small pool of seeded feature matrices in turn, one
+call at a time or back to back, as the traffic's loop says.
 
 The check compares every sampled output with the plain segment-sum
 reference (``bench/references/graph_agg.py``): the largest error of any
@@ -89,15 +90,21 @@ class System:
     # full precision (three bfloat16 passes).
     CONTROL = "high"
 
-    def __init__(self, cfg: dict, traffic: dict, seed: int, clock):
+    def __init__(self, cfg: dict, traffic: dict, seed: int, clock,
+                 devices):
         self.cfg, self.traffic, self.seed, self.clock = cfg, traffic, seed, \
             clock
+        # One chip, the default device, where set-up keeps all the state.
+        if list(devices) != jax.devices()[:1]:
+            raise ValueError(f"runs on the default device alone, not on "
+                             f"{list(devices)}")
+        self.devices = devices
         self.n = 1 << int(cfg["SCALE"])
         self.f = int(traffic["features"])
 
     def setup(self) -> None:
         from repro.core import CSR, PlanPolicy, SparseMatrix
-        if self.traffic["loop"] != "closed_batch":
+        if self.traffic["loop"] not in ("closed_batch", "pipelined"):
             raise ValueError(f"graph_agg has no loop "
                              f"{self.traffic['loop']!r}")
         with self.clock.phase("generate"):

@@ -121,9 +121,15 @@ class System:
     # The control: the reference one precision step below bfloat16.
     CONTROL = "fp8"
 
-    def __init__(self, cfg: dict, traffic: dict, seed: int, clock):
+    def __init__(self, cfg: dict, traffic: dict, seed: int, clock,
+                 devices):
         self.cfg, self.traffic, self.seed, self.clock = cfg, traffic, seed, \
             clock
+        # One chip, the default device, where set-up keeps all the state.
+        if list(devices) != jax.devices()[:1]:
+            raise ValueError(f"runs on the default device alone, not on "
+                             f"{list(devices)}")
+        self.devices = devices
         self.dims = _dims(cfg)
         self.nnz_per_layer = sum(
             m * max(1, int(round(cfg[KEEP_KEY] * kk)))
@@ -147,7 +153,7 @@ class System:
         from repro.core import CSR, PlanPolicy
         from repro.launch import serve
         from repro.models.sparse import SparseLinear
-        if self.traffic["loop"] != "closed_batch":
+        if self.traffic["loop"] not in ("closed_batch", "pipelined"):
             raise ValueError(f"pruned_lm has no loop "
                              f"{self.traffic['loop']!r}")
         key = jax.random.PRNGKey(T.jax_seed(self.seed, 0))
@@ -191,8 +197,7 @@ class System:
         return int(self.prompts[i % len(self.prompts)].size)
 
     def unit_work(self, i: int) -> dict:
-        """Required operations and the SpMM calls of closed-batch call
-        ``i``."""
+        """Required operations and the SpMM calls of call ``i``."""
         b, s = self.prompts[i % len(self.prompts)].shape
         return {"flops": b * work.lm_flops(self.cfg, self.nnz_per_layer, s),
                 "spmm": self.spmm_calls(b * s)}

@@ -1,47 +1,33 @@
-"""Tiny copies of the benchmark's cells for CPU tests.
+"""The benchmark's CPU tests, at tiny sizes.
 
     JAX_PLATFORMS=cpu python -m pytest bench/tests
 
-``tiny_root`` builds, in a temporary directory, a checkout-shaped tree:
-``BENCHMARK.json`` and a copy of ``bench/`` whose configuration files are
-cut to a few thousand parameters or nodes.  The harness runs there with
-its look for a chip skipped; Pallas kernels run in interpret mode.
+They also run with the repository's own tests, in one pytest session and
+under several workers: ``bench/tests`` is a package (``bench.tests``), so
+no module name clashes with ``tests/``, and the helpers are imported from
+``bench.tests.helpers``, never from a ``conftest``.
+
+Each configuration in ``BENCHMARK.json`` brings its tiny sizes in
+``bench/tests/tiny/<config>.json``; ``tiny_root`` builds, in a temporary
+directory, a checkout-shaped tree at those sizes, and fails at once,
+naming the file to add, where one is missing.  Cells on one chip run in
+the test's own process.  A cell on several chips runs in a child process
+whose CPU backend is given that many devices
+(``helpers.run_cell_on_devices``), since a process's device count is
+fixed once its backend has started, and another test module may have
+started it.
 """
-import json
 import os
-import shutil
-import sys
-import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import pytest  # noqa: E402
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
+from bench.tests.helpers import make_tiny_root  # noqa: E402
 
-TINY = {
-    "granite-3-2b": dict(hidden_size=64, intermediate_size=128,
-                         num_hidden_layers=2, num_attention_heads=4,
-                         num_key_value_heads=2, vocab_size=512),
-    "graph500-s16": dict(SCALE=8),
-}
-
-
-def make_tiny_root(path: str) -> str:
-    shutil.copytree(os.path.join(REPO, "bench"), os.path.join(path, "bench"),
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), path)
-    for name, sizes in TINY.items():
-        f = os.path.join(path, "bench", "configs", name + ".json")
-        with open(f) as fh:
-            cfg = json.load(fh)
-        cfg.update(sizes)
-        with open(f, "w") as fh:
-            json.dump(cfg, fh)
-    return path
+# What ``harness.prepare_jax`` sets, process-wide, for a run.
+_JAX_OPTIONS = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
 
 
 @pytest.fixture(scope="session")
@@ -49,17 +35,16 @@ def tiny_root(tmp_path_factory):
     return make_tiny_root(str(tmp_path_factory.mktemp("tiny")))
 
 
-def run_cell(root, cell, *, seed=2147483653, seconds=1.0, trace=0):
-    """One harness run of ``cell`` under ``root``; returns (rc, result)."""
-    import contextlib
-    import io
-
-    from bench import harness
-    out = io.StringIO()
-    with contextlib.redirect_stdout(out):
-        rc = harness.main(["--workload", cell, "--seed", str(seed),
-                           "--seconds", str(seconds), "--trace", str(trace)],
-                          root=root,
-                          require_tpu=False)
-    lines = out.getvalue().strip().splitlines()
-    return rc, (json.loads(lines[-1]) if lines else None)
+@pytest.fixture(autouse=True)
+def _jax_options_restored():
+    """A run in this process turns JAX's persistent compilation cache on;
+    turn it back as it was, so that the next test in this worker, of
+    whichever suite, runs as it would alone."""
+    import jax
+    before = {k: getattr(jax.config, k) for k in _JAX_OPTIONS}
+    yield
+    if any(getattr(jax.config, k) != v for k, v in before.items()):
+        from jax.experimental.compilation_cache import compilation_cache
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

@@ -4,7 +4,7 @@ chip skipped.  Says nothing about speed."""
 import pytest
 
 from bench.harness import load_json
-from conftest import REPO, run_cell
+from bench.tests.helpers import REPO, run_cell
 
 CELLS = [w["name"] for w in
          load_json(f"{REPO}/BENCHMARK.json")["workloads"]]

@@ -7,7 +7,7 @@ builds the programs the window drives, at tiny size on the CPU.
 """
 import pytest
 
-from conftest import run_cell
+from bench.tests.helpers import run_cell
 
 
 def _swap_first_positions(monkeypatch):
@@ -67,7 +67,8 @@ def test_control_fails_the_limits(tiny_root, cell):
     c = harness.resolve_cell(tiny_root, cell)
     harness.prepare_jax(tiny_root)
     cls = harness.system_class(c)
-    system = cls(c.config, c.traffic, 5, harness.SetupClock())
+    system = cls(c.config, c.traffic, 5, harness.SetupClock(),
+                 harness.chips(c.chips, require_tpu=False))
     system.setup()
     sampler = harness.Sampler(int(c.traffic["sample"]), 5)
     harness.LOOPS[c.traffic["loop"]](system, 1.0, sampler)

@@ -5,7 +5,7 @@ import shutil
 import subprocess
 import sys
 
-from conftest import REPO
+from bench.tests.helpers import REPO
 
 ARGS = ["--workload", "granite-3-2b.score-128", "--seed", "3",
         "--seconds", "1", "--trace", "0"]

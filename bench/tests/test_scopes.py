@@ -98,7 +98,7 @@ def test_instruction_name():
 
 def test_plan_host_reads_fingerprint_and_row_ids():
     from bench.harness import metric_reader
-    from conftest import REPO
+    from bench.tests.helpers import REPO
     from repro import obs
     read = metric_reader(REPO, "plan_host_s")
     fam = obs.registry.histogram("plan_build_step_seconds",

@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from bench import readers, trace_reduce
+from bench import readers, trace_reduce, work
 from bench.trace_reduce import Op, Trace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -55,8 +55,6 @@ def test_idle_gaps_are_named_by_the_innermost_host_span():
 
 
 def test_roofline_and_idle_readers():
-    from bench import work
-
     @dataclasses.dataclass
     class W:
         spmm: list
@@ -66,6 +64,7 @@ def test_roofline_and_idle_readers():
         trace: Trace
         window: W
         peak: dict
+        chips: int = 1
 
     call = work.SpmmCall(m=8, k=8, nnz=16, n=128, val_bytes=4, b_bytes=4,
                          c_bytes=4)
@@ -80,6 +79,46 @@ def test_roofline_and_idle_readers():
     run.trace.devices = [[]]
     assert readers.idle_share(run) is None
     assert readers.spmm_roofline(run) is None
+
+
+@pytest.mark.parametrize("chips", [2, 4])
+def test_shares_read_over_all_the_cells_chips(chips):
+    """The same work on ``chips`` chips, each busy for 1/chips of one
+    chip's kernel time and window: every share reads what one chip's
+    does."""
+    from bench.harness import load_module
+
+    @dataclasses.dataclass
+    class W:
+        spmm: list
+        flops: float
+        seconds: float
+
+    @dataclasses.dataclass
+    class R:
+        trace: Trace
+        window: W
+        peak: dict
+        chips: int
+
+    k = 'custom_call_target="tpu_custom_call"'
+
+    def run(n):
+        ops = [Op(f"%rowsplit_execute.3 = f32[8] custom-call(), {k}", 10,
+                  10 + 400 / n, {})]
+        call = work.SpmmCall(m=4096, k=4096, nnz=65536, n=128, val_bytes=4,
+                             b_bytes=2, c_bytes=2)
+        return R(Trace(window=(0.0, 1000.0 / n), devices=[ops] * n,
+                       host=[]),
+                 W([call], 1e9, 1e-6 / n), PEAK, n)
+
+    mfu = load_module(os.path.join(os.path.dirname(DATA), os.pardir,
+                                   "metrics", "step_mfu.py"), "mfu").read
+    one, many = run(1), run(chips)
+    assert readers.spmm_roofline(many) == pytest.approx(
+        readers.spmm_roofline(one), rel=1e-12)
+    assert mfu(many) == pytest.approx(mfu(one), rel=1e-12)
+    assert readers.idle_share(many) == pytest.approx(readers.idle_share(one))
 
 
 def test_recorded_host_trace_parses(tmp_path):

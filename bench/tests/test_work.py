@@ -58,6 +58,7 @@ def test_step_mfu_reader():
     class R:
         window: W
         peak: dict
+        chips: int
 
     # 1.97e12 flops in 1 s on a 197e12 chip: 1%.
-    assert abs(read(R(W(1.97e12, 1.0), PEAK)) - 1.0) < 1e-12
+    assert abs(read(R(W(1.97e12, 1.0), PEAK, 1)) - 1.0) < 1e-12

@@ -24,8 +24,8 @@ def jax_seed(seed: int, stream: int) -> int:
 
 
 def prompts(traffic: dict, seed: int, vocab: int) -> list[np.ndarray]:
-    """Closed-batch loop: ``pool`` distinct ``(batch, length)`` token
-    batches; call ``i`` of the window sends batch ``i % pool``."""
+    """``pool`` distinct ``(batch, length)`` token batches; call ``i`` of
+    the window sends batch ``i % pool``."""
     r = rng(seed, 1)
     shape = (int(traffic["batch"]), int(traffic["length"]))
     return [r.integers(0, vocab, size=shape, dtype=np.int32)
