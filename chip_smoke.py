@@ -343,13 +343,14 @@ def check_placement(blocks, mesh) -> int:
                 check(len(shards) == len(devices),
                       f"{name}: {len(shards)} shards of a plan leaf for "
                       f"{len(devices)} devices")
+                d0 = leaf.shape[0] // len(devices)
                 for s in shards:
-                    i = s.index[0].start or 0
+                    i = (s.index[0].start or 0) // d0
                     check(s.device == devices[i],
                           f"{name}: shard {i} on {s.device}, expected "
                           f"{devices[i]}")
                     check(bi > 0 or np.array_equal(
-                        np.asarray(s.data)[0], np.asarray(per_shard[i][li])),
+                        np.asarray(s.data), np.asarray(per_shard[i][li])),
                         f"{name}: device {s.device} holds the wrong slice "
                         "of a plan leaf")
                 n += 1

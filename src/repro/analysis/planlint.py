@@ -469,6 +469,27 @@ def verify_plan(plan, a=None) -> list:
     return diags
 
 
+def _check_shard_ranges(meta, bounds, covered, a, diags) -> None:
+    """Shard ``i`` gathers exactly the global nonzeros of its range: rows
+    ``[bounds[i], bounds[i+1])`` (or those columns).  Padded local shapes
+    hide a moved interior bound from the shape checks; this does not."""
+    rp = _np(a.row_ptr).astype(np.int64)
+    if meta.dim == "cols":
+        nnz = int(rp[-1])
+        cols = _np(a.col_ind)[:nnz]
+    for i, live in enumerate(covered):
+        lo, hi = int(bounds[i]), int(bounds[i + 1])
+        if meta.dim == "rows":
+            want = np.arange(rp[lo], rp[hi])
+        else:
+            want = np.nonzero((cols >= lo) & (cols < hi))[0]
+        if not np.array_equal(np.sort(live), want):
+            diags.append(Diagnostic(
+                "P072", f"plan.vals_slots[{i}]",
+                f"gathers {live.size} value(s), not the {want.size} "
+                f"nonzero(es) of {meta.dim} [{lo}, {hi})"))
+
+
 def verify_sharded_plan(plan, a=None) -> list:
     """Verify a ``ShardedSpmmPlan``: shard layout, per-shard plans, and
     the global value-gather coverage."""
@@ -528,6 +549,8 @@ def verify_sharded_plan(plan, a=None) -> list:
                 "P072", f"plan.vals_slots[{i}]",
                 f"gathers {live.size} live value(s) but the shard plan "
                 f"holds {int(local_valid.sum())} nonzero(es)"))
+    if a is not None:
+        _check_shard_ranges(meta, bounds, covered, a, diags)
     ids = np.concatenate(covered) if covered else np.zeros(0, np.int64)
     nnz = int(_np(a.row_ptr)[-1]) if a is not None else ids.size
     _check_coverage(

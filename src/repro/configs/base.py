@@ -55,8 +55,13 @@ class ModelConfig:
     input_mode: str = "tokens"  # tokens | embeddings (audio/vlm stub frontend)
     tie_embeddings: bool = True
     norm: str = "rmsnorm"       # rmsnorm | layernorm
+    norm_eps: float = 1e-6
+    norm_bias: bool = True      # layernorm's bias (False: weight-only)
     rope_theta: float = 10_000.0  # 0 → sinusoidal absolute positions
+    # rotary pairs (x[2i], x[2i+1]) (GPT-J / Cohere) instead of rotate-half
+    rope_interleaved: bool = False
     logit_softcap: float = 0.0
+    logit_scale: float = 1.0      # logits multiplied by this (Cohere)
     parallel_block: bool = False  # command-r style parallel attn+FFN
     embed_scale: bool = False     # multiply embeddings by sqrt(d)
     mlp: str = "swiglu"           # swiglu | gelu

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 import types
 
@@ -283,9 +284,13 @@ def build_plan(a: CSR, *, method: str = "auto",
         nz_rows = np.zeros(nnz_pad, np.int32)
         nz_rows[:nnz] = np.repeat(np.arange(a.m, dtype=np.int32),
                                   np.diff(rp))
-        fwd["nz_rows"] = jnp.asarray(nz_rows)
+        # Host-built arrays join the pattern where it lies (a shard's
+        # plan on its own device, repro.distributed.spmm).
+        put = functools.partial(jax.device_put, device=a.col_ind.sharding) \
+            if getattr(a.col_ind, "committed", False) else jnp.asarray
+        fwd["nz_rows"] = put(nz_rows)
         fwd["nz_cols"] = a.col_ind
-        fwd["nz_valid"] = jnp.asarray(np.arange(nnz_pad) < nnz)
+        fwd["nz_valid"] = put(np.arange(nnz_pad) < nnz)
     plan = SpmmPlan(fwd=fwd, bwd=bwd, meta=meta)
     if _verify_flags.verify_plans:
         # Opt-in debug hook (REPRO_VERIFY_PLANS=1): full host-side

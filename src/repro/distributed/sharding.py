@@ -87,7 +87,9 @@ _MOE_3D = {"w1": (None, "data", "model"), "w3": (None, "data", "model"),
 def param_pspec(path, leaf, mesh: Mesh, mode: str = "fsdp") -> P:
     """mode: "fsdp" (data-FSDP × model-TP), "zero1" (model-TP only —
     compute replica; master is FSDP inside the optimizer), "fsdp2"
-    (pure ZeRO-3 over the flattened data×model axes, no TP)."""
+    (pure ZeRO-3 over the flattened data×model axes, no TP), "tp"
+    (serving: model-TP only, with the embedding's vocabulary rows over
+    model too)."""
     name = None
     for k in reversed(path):
         if isinstance(k, jax.tree_util.DictKey):
@@ -109,6 +111,9 @@ def param_pspec(path, leaf, mesh: Mesh, mode: str = "fsdp") -> P:
             # token buffer to the expert layout at 9× the wire bytes.
     if mode == "zero1":       # compute replica: model axes only
         rules = tuple(None if r == "data" else r for r in rules)
+    elif mode == "tp":        # + vocabulary-parallel embedding
+        rules = ("model", None) if name in ("embed", "unembed") else \
+            tuple(None if r == "data" else r for r in rules)
     elif mode == "fsdp2":     # ZeRO-3 over every device, no TP
         dpm = dp_axes(mesh) + ("model",)
         rules = tuple(dpm if r == "data" else None for r in rules)

@@ -53,6 +53,7 @@ from repro.core.config import (ExecutionConfig, PlanPolicy, _UNSET,
 from repro.core.csr import CSR
 from repro.core.plan import SpmmPlan, build_plan
 from repro.core.spmm import execute_plan
+from repro.obs import scope
 from repro.obs import trace as _trace
 
 # Shard-balance gauges are plan-time (amortized) and stay always-on; the
@@ -286,12 +287,14 @@ class ShardedSpmmPlan:
     A pytree (the shard layout is static aux data), so it lives inside
     model pytrees and passes through jit boundaries exactly like a
     single-device ``SpmmPlan``.  A plan that runs as one SPMD program
-    flattens to its stacked leaves, placed one shard per mesh device
-    (:meth:`_stacked`): a jitted caller then receives every shard on its
-    own device once, at placement, instead of all of them on one device
-    and moved every call.  Other plans flatten to the per-shard plans and
-    gather indices.  Execute with :func:`execute_sharded` (or ``A @ B`` on
-    a sharded ``SparseMatrix``).
+    flattens to its stacked leaves: each leaf the shard-major
+    concatenation of the shards' leaves along their first axis, sharded
+    over the mesh axis so that shard ``i``'s block is the array the shard's
+    plan was built as, on its own device (:meth:`_stacked`).  A jitted
+    caller then receives every shard where it lives, and no device holds
+    another's.  Other plans flatten to the per-shard plans and gather
+    indices.  Execute with :func:`execute_sharded` (or ``A @ B`` on a
+    sharded ``SparseMatrix``).
     """
 
     shards: tuple[SpmmPlan, ...]
@@ -317,15 +320,19 @@ class ShardedSpmmPlan:
         cache = self.__dict__.get("_stack_cache")
         if name not in ("shards", "vals_slots", "b_rows") or cache is None:
             raise AttributeError(name)
-        stacked_plan, slot_stack, brow_stack = cache
         n = self.meta.n_shards
+
+        def block(x, i):
+            d0 = x.shape[0] // n
+            return x[i * d0:(i + 1) * d0]
+        stacked_plan, slot_stack, brow_stack = cache
         object.__setattr__(self, "shards", tuple(
-            jax.tree.map(lambda x, i=i: x[i], stacked_plan)
+            jax.tree.map(lambda x, i=i: block(x, i), stacked_plan)
             for i in range(n)))
         object.__setattr__(self, "vals_slots",
-                           tuple(slot_stack[i] for i in range(n)))
+                           tuple(block(slot_stack, i) for i in range(n)))
         object.__setattr__(self, "b_rows", None if brow_stack is None else
-                           tuple(brow_stack[i] for i in range(n)))
+                           tuple(block(brow_stack, i) for i in range(n)))
         return self.__dict__[name]
 
     # Stacked leaves for the SPMD path, memoized per live (concrete) plan
@@ -335,20 +342,30 @@ class ShardedSpmmPlan:
         cached = getattr(self, "_stack_cache", None)
         if cached is not None:
             return cached
-        stacked_plan = jax.tree.map(lambda *xs: jnp.stack(xs), *self.shards)
-        slot_stack = jnp.stack(self.vals_slots)
-        brow_stack = jnp.stack(self.b_rows) if self.b_rows else None
+        leaves = [self.shards, self.vals_slots, self.b_rows]
         mesh = self.meta.spmd_mesh()
         concrete = not any(isinstance(x, jax.core.Tracer)
-                           for x in jax.tree.leaves(stacked_plan))
+                           for x in jax.tree.leaves(leaves))
         if concrete and mesh is not None:
-            # Pre-place shard-major leaves on the mesh axis so shard_map
-            # never reshards per call.
+            # Each shard's block is its own array, moved only if it was
+            # built elsewhere than on its device (_shard_devices).
             sh = NamedSharding(mesh, P(self.meta.axis))
-            stacked_plan = jax.device_put(stacked_plan, sh)
-            slot_stack = jax.device_put(slot_stack, sh)
-            if brow_stack is not None:
-                brow_stack = jax.device_put(brow_stack, sh)
+
+            def stack(*xs):
+                d0 = xs[0].shape[0]
+                shape = (len(xs) * d0,) + xs[0].shape[1:]
+                return jax.make_array_from_single_device_arrays(
+                    shape, sh, [jax.device_put(xs[(ix[0].start or 0) // d0],
+                                               d)
+                                for d, ix in
+                                sh.addressable_devices_indices_map(
+                                    shape).items()])
+        else:
+            def stack(*xs):
+                return jnp.concatenate(xs)
+        stacked_plan = jax.tree.map(stack, *self.shards)
+        slot_stack = stack(*self.vals_slots)
+        brow_stack = stack(*self.b_rows) if self.b_rows else None
         out = (stacked_plan, slot_stack, brow_stack)
         if concrete:
             object.__setattr__(self, "_stack_cache", out)
@@ -392,6 +409,16 @@ def _unify_params(rs) -> tuple:
     return t, tl, (max(pads) if pads else None)
 
 
+def _shard_devices(mesh, axis: str, n: int) -> list:
+    """The first device holding shard ``i`` of ``n`` split over ``axis``
+    of ``mesh``, for each ``i``."""
+    sh = NamedSharding(mesh, P(axis))
+    first: dict = {}
+    for d, ix in sh.addressable_devices_indices_map((n,)).items():
+        first.setdefault(ix[0].start or 0, d)
+    return [first[i] for i in range(n)]
+
+
 def build_sharded_plan(a: CSR, policy: PlanPolicy,
                        cache=None) -> ShardedSpmmPlan:
     """Shard ``a`` by nnz and plan each shard independently.
@@ -431,6 +458,7 @@ def build_sharded_plan(a: CSR, policy: PlanPolicy,
                nnz_imbalance=round(imbalance, 4))
     methods = {r.method for r in resolved}
     stackable = False
+    slots = shards.vals_slots
     if len(methods) == 1:
         # One method everywhere: unify the static params and check that
         # the method derives identical method-specific statics on the
@@ -445,6 +473,16 @@ def build_sharded_plan(a: CSR, policy: PlanPolicy,
                              l_pad=l_pad, tunedb=None,
                              with_transpose=policy.with_transpose)] * n
         build_csrs = shards.csrs
+        mesh = spec.mesh
+        if mesh is not None and spec.axis in mesh.axis_names and \
+                mesh.shape[spec.axis] == n:
+            # One program over the mesh: each shard is planned on its own
+            # device, where its plan then stays (no device ever holds, or
+            # builds, another shard's plan).
+            homes = _shard_devices(mesh, spec.axis, n)
+            build_csrs = [jax.device_put(c, d)
+                          for c, d in zip(build_csrs, homes)]
+            slots = tuple(jax.device_put(s, d) for s, d in zip(slots, homes))
     else:
         # Heterogeneous shards run the per-shard loop, where shape
         # padding buys nothing and can cost plenty (a rowsplit shard
@@ -468,7 +506,7 @@ def build_sharded_plan(a: CSR, policy: PlanPolicy,
                        bounds=shards.bounds, axis=spec.axis, mesh=spec.mesh,
                        uniform=uniform, local_metas=tuple(p.meta
                                                           for p in plans))
-    plan = ShardedSpmmPlan(shards=plans, vals_slots=shards.vals_slots,
+    plan = ShardedSpmmPlan(shards=plans, vals_slots=slots,
                            b_rows=shards.b_rows, meta=meta)
     if _verify_flags.verify_plans:
         # REPRO_VERIFY_PLANS debug hook: the per-shard plans were each
@@ -567,10 +605,13 @@ def _execute_loop(plan, vals, b, exec):
     outs = []
     for i, (p, slot) in enumerate(zip(plan.shards, plan.vals_slots)):
         lb = _local_b(b, plan.b_rows[i]) if meta.dim == "cols" else b
-        outs.append(execute_plan(p, _local_vals(vals, slot), lb, exec))
-    if meta.dim == "rows":
-        return _concat_rows(outs, meta.bounds)
-    return sum(outs[1:], outs[0])
+        with scope("shard_vals"):
+            lv = _local_vals(vals, slot)
+        outs.append(execute_plan(p, lv, lb, exec))
+    with scope("shard_concat"):
+        if meta.dim == "rows":
+            return _concat_rows(outs, meta.bounds)
+        return sum(outs[1:], outs[0])
 
 
 def _execute_spmd(plan, vals, b, exec, mesh):
@@ -579,25 +620,28 @@ def _execute_spmd(plan, vals, b, exec, mesh):
     axis = meta.axis
     stacked_plan, slot_stack, brow_stack = plan._stacked()
 
+    # Each device's block of a stacked leaf is its own shard's leaf.
     if meta.dim == "rows":
         def body(plan_s, slot_s, vals, b):
-            local = jax.tree.map(lambda x: x[0], plan_s)
-            out = execute_plan(local, _local_vals(vals, slot_s[0]), b, exec)
-            return out[None]
+            with scope("shard_vals"):
+                lv = _local_vals(vals, slot_s)
+            return execute_plan(plan_s, lv, b, exec)[None]
 
         out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P()),
             out_specs=P(axis), check_vma=False,
         )(stacked_plan, slot_stack, vals, b)
-        return _concat_rows([out[i] for i in range(meta.n_shards)],
-                            meta.bounds)
+        with scope("shard_concat"):
+            return _concat_rows([out[i] for i in range(meta.n_shards)],
+                                meta.bounds)
 
     def body(plan_s, slot_s, brow_s, vals, b):
-        local = jax.tree.map(lambda x: x[0], plan_s)
-        partial = execute_plan(local, _local_vals(vals, slot_s[0]),
-                               _local_b(b, brow_s[0]), exec)
-        return jax.lax.psum(partial, axis)
+        with scope("shard_vals"):
+            lv = _local_vals(vals, slot_s)
+        partial = execute_plan(plan_s, lv, _local_b(b, brow_s), exec)
+        with scope("shard_concat"):
+            return jax.lax.psum(partial, axis)
 
     return jax.shard_map(
         body, mesh=mesh,

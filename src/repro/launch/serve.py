@@ -11,6 +11,7 @@ with optional pruned-FFN SpMM (the paper's use case).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -123,12 +124,66 @@ def make_pruned_forward(cfg):
         for btype, lp in zip(btypes, blocks):
             h, _, _ = M.block_apply(lp, btype, h, cfg)
         with obs.scope("norm"):
-            h = L.norm_apply(params["final_norm"], h, cfg.norm)
+            h = L.norm_apply(params["final_norm"], h, cfg.norm,
+                             cfg.norm_eps)
         with obs.scope("unembed"):
-            return h.astype(jnp.float32) @ M.unembed_matrix(
-                params, cfg).T.astype(jnp.float32)
+            return M.final_logits(h.astype(jnp.float32) @ M.unembed_matrix(
+                params, cfg).T.astype(jnp.float32), cfg)
 
     return fwd
+
+
+def dense_shardings(tree, mesh):
+    """Where each dense weight of a pruned model lies on the one-axis
+    serving mesh: the embedding by vocabulary rows, ``wq``/``wk``/``wv``
+    by output heads and ``wo`` by input heads over the mesh's axis, every
+    other leaf (norms) whole on each device.  The rules are
+    ``sharding.param_pspec``'s tensor-parallel ones (mode ``"tp"``), their
+    ``model`` axis read as the mesh's one axis.  ``tree`` may hold arrays
+    or ``ShapeDtypeStruct``s."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed.sharding import param_pspec
+    (axis,) = mesh.axis_names
+    tp = Mesh(mesh.devices.reshape(1, -1), ("data", "model"))
+
+    def one(path, leaf):
+        spec = param_pspec(path, leaf, tp, mode="tp")
+        return NamedSharding(mesh, P(*[axis if a == "model" else a
+                                       for a in spec]))
+    return jax.tree_util.tree_map_with_path(one, tree)
+
+
+def place_on_mesh(head, blocks, mesh):
+    """A pruned model's weights placed on the serving mesh its sharded
+    SpMM plans run over: the dense weights as ``dense_shardings`` says,
+    each pruned layer's global CSR whole on every device (the shard_map
+    gathers each shard's values from it every call), its plan's shards
+    where the plan put them.  Returns ``(head, blocks)``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    everywhere = NamedSharding(mesh, P())
+    head = jax.device_put(head, dense_shardings(head, mesh))
+    placed = []
+    for lp in blocks:
+        dense = {k: v for k, v in lp.items() if k != "mlp"}
+        out = dict(jax.device_put(dense, dense_shardings(dense, mesh)))
+        out["mlp"] = {name: dataclasses.replace(
+            sl, weight=jax.device_put(sl.weight, everywhere))
+            for name, sl in lp["mlp"].items()}
+        placed.append(out)
+    return head, placed
+
+
+def _placed(params, blocks, policy):
+    """With a sharded plan policy over a mesh, the weights the pruned
+    forward reads placed on that mesh (``place_on_mesh``); else as
+    given."""
+    mesh = policy.shards.mesh if policy is not None and \
+        policy.shards is not None else None
+    if mesh is None:
+        return params, blocks
+    head = {k: v for k, v in params.items() if k != "segments"}
+    return place_on_mesh(head, blocks, mesh)
 
 
 def serve_pruned(cfg, params, prompt, keep: float, *, microbatch: int = 0,
@@ -144,6 +199,7 @@ def serve_pruned(cfg, params, prompt, keep: float, *, microbatch: int = 0,
     if blocks is None:
         with obs.span("serve.plan", cat="serve", keep=keep):
             blocks = prune_ffn_blocks(params, cfg, keep, policy=policy)
+    params, blocks = _placed(params, blocks, policy)
     t_plan = time.perf_counter() - t0
     _serve_latency.labels(phase="plan").observe(t_plan * 1e6)
     stats = engine.cache_stats()
@@ -189,6 +245,7 @@ def serve_online(cfg, params, keep: float, args, policy=None) -> int:
     check_prunable(cfg)
     with obs.span("serve.plan", cat="serve", keep=keep):
         blocks = prune_ffn_blocks(params, cfg, keep, policy=policy)
+    params, blocks = _placed(params, blocks, policy)
     base = make_pruned_forward(cfg)
 
     def forward(state, tokens):
@@ -266,7 +323,9 @@ def main(argv=None):
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
                     help="shard every pruned-FFN weight over a N-device "
                     "data mesh: nnz-balanced row shards, one local plan "
-                    "per shard, executed as a single shard_map program "
+                    "per shard, executed as a single shard_map program; "
+                    "the embedding by vocabulary rows and attention by "
+                    "heads over the same devices "
                     "(CPU dev boxes: XLA_FLAGS="
                     "--xla_force_host_platform_device_count=N)")
     ap.add_argument("--trace-out", default="", metavar="PATH",
@@ -334,8 +393,6 @@ def main(argv=None):
     prompt = jax.random.randint(key, (args.batch, args.prompt_len), 0,
                                 cfg.vocab_size)
     if args.prune_ffn > 0.0:
-        import dataclasses
-
         from repro.core import PlanPolicy, ShardSpec
         # Serving never differentiates: no backward (transpose) plans.
         policy = PlanPolicy(method=args.spmm_method, with_transpose=False)

@@ -17,9 +17,9 @@ from repro.distributed.sharding import constrain
 # ---------------------------------------------------------------- norms ----
 
 
-def init_norm(d: int, kind: str, dtype) -> dict:
+def init_norm(d: int, kind: str, dtype, bias: bool = True) -> dict:
     p = {"scale": jnp.ones((d,), dtype)}
-    if kind == "layernorm":
+    if kind == "layernorm" and bias:
         p["bias"] = jnp.zeros((d,), dtype)
     return p
 
@@ -51,17 +51,26 @@ def norm_apply(p: dict, x: jax.Array, kind: str = "rmsnorm",
 # ----------------------------------------------------------------- RoPE ----
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x (..., s, h, dh), positions (..., s) broadcastable."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         interleaved: bool = False) -> jax.Array:
+    """x (..., s, h, dh), positions (..., s) broadcastable.  Pair ``i``
+    turns by ``positions * theta ** (-i / (dh / 2))``; the pairs are
+    ``(x[i], x[i + dh/2])`` (rotate-half) or, ``interleaved``,
+    ``(x[2i], x[2i+1])``."""
     dh = x.shape[-1]
     half = dh // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freq   # (..., s, half)
     ang = ang[..., None, :]                            # (..., s, 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
+        out = jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -271,8 +280,8 @@ def attention_apply(p, x, cfg, *, window=None, positions=None,
     if positions is None:
         positions = jnp.arange(s)[None, :]
     if cfg.rope_theta:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_interleaved)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_interleaved)
     softcap = cfg.attn_logit_softcap
     # larger flash tiles cut online-softmax carry traffic ~10% (§Perf
     # iteration 7); capped at 512 for long sequences to bound the f32

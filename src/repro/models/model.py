@@ -33,25 +33,25 @@ from .losses import chunked_cross_entropy
 
 def init_block(key, btype: str, cfg) -> dict:
     ks = jax.random.split(key, 4)
-    nk = cfg.norm
+    nk, nb = cfg.norm, cfg.norm_bias
     d = cfg.d_model
     if btype in ("attn", "moe"):
-        p = {"ln1": L.init_norm(d, nk, jnp.float32),
+        p = {"ln1": L.init_norm(d, nk, jnp.float32, nb),
              "attn": L.init_attention(ks[0], cfg)}
         if btype == "attn":
-            p["ln2"] = L.init_norm(d, nk, jnp.float32)
+            p["ln2"] = L.init_norm(d, nk, jnp.float32, nb)
             p["mlp"] = L.init_mlp(ks[1], cfg)
         else:
-            p["ln2"] = L.init_norm(d, nk, jnp.float32)
+            p["ln2"] = L.init_norm(d, nk, jnp.float32, nb)
             p["moe"] = M.init_moe(ks[1], cfg)
         return p
     if btype == "ssd":
-        return {"ln1": L.init_norm(d, nk, jnp.float32),
+        return {"ln1": L.init_norm(d, nk, jnp.float32, nb),
                 "ssd": S.init_ssd(ks[0], cfg)}
     if btype == "rglru":
-        return {"ln1": L.init_norm(d, nk, jnp.float32),
+        return {"ln1": L.init_norm(d, nk, jnp.float32, nb),
                 "rec": R.init_rglru(ks[0], cfg),
-                "ln2": L.init_norm(d, nk, jnp.float32),
+                "ln2": L.init_norm(d, nk, jnp.float32, nb),
                 "mlp": L.init_mlp(ks[1], cfg)}
     raise ValueError(btype)
 
@@ -84,7 +84,8 @@ def block_apply(p, btype, x, cfg, *, positions=None, cache=None, pos=None):
     # profiler join; each sublayer's residual add rides in its scope.
     if btype in ("attn", "moe"):
         with scope("norm"):
-            h = constrain(L.norm_apply(p["ln1"], x, cfg.norm), *rs)
+            h = constrain(L.norm_apply(p["ln1"], x, cfg.norm, cfg.norm_eps),
+                          *rs)
         with scope("attn"):
             attn_out, new_cache = L.attention_apply(
                 p["attn"], h, cfg, window=_window(cfg), positions=positions,
@@ -100,7 +101,8 @@ def block_apply(p, btype, x, cfg, *, positions=None, cache=None, pos=None):
             with scope("attn"):
                 x = x + attn_out
             with scope("norm"):
-                h2 = constrain(L.norm_apply(p["ln2"], x, cfg.norm), *rs)
+                h2 = constrain(
+                    L.norm_apply(p["ln2"], x, cfg.norm, cfg.norm_eps), *rs)
             with scope("mlp"):
                 if btype == "moe":
                     ffn_out, aux = M.moe_apply(p["moe"], h2, cfg)
@@ -112,18 +114,18 @@ def block_apply(p, btype, x, cfg, *, positions=None, cache=None, pos=None):
     # computed from scratch (the passed cache is only a shape donor).
     if btype == "ssd":
         with scope("norm"):
-            h = L.norm_apply(p["ln1"], x, cfg.norm)
+            h = L.norm_apply(p["ln1"], x, cfg.norm, cfg.norm_eps)
         state = cache if (cache is not None and x.shape[1] == 1) else None
         out, new_cache = S.ssd_apply(p["ssd"], h, cfg, state=state)
         return x + out, new_cache, aux
     if btype == "rglru":
         with scope("norm"):
-            h = L.norm_apply(p["ln1"], x, cfg.norm)
+            h = L.norm_apply(p["ln1"], x, cfg.norm, cfg.norm_eps)
         state = cache if (cache is not None and x.shape[1] == 1) else None
         out, new_cache = R.rglru_apply(p["rec"], h, cfg, state=state)
         x = x + out
         with scope("norm"):
-            h2 = L.norm_apply(p["ln2"], x, cfg.norm)
+            h2 = L.norm_apply(p["ln2"], x, cfg.norm, cfg.norm_eps)
         with scope("mlp"):
             x = x + L.mlp_apply(p["mlp"], h2, cfg)
         return x, new_cache, aux
@@ -146,7 +148,8 @@ def init_params(cfg, key) -> dict:
     else:  # stub modality frontend provides embeddings; head is untied
         params["unembed"] = (jax.random.normal(keys[1], (v, d), cfg.pdtype)
                              * d ** -0.5)
-    params["final_norm"] = L.init_norm(d, cfg.norm, jnp.float32)
+    params["final_norm"] = L.init_norm(d, cfg.norm, jnp.float32,
+                                       cfg.norm_bias)
 
     segments = []
     kseg = keys[2]
@@ -234,6 +237,16 @@ def unembed_matrix(params, cfg):
     return params.get("unembed", params.get("embed"))
 
 
+def final_logits(logits, cfg):
+    """The head's published scaling of raw logits: ``logit_scale``, then
+    the soft cap."""
+    if cfg.logit_scale != 1.0:
+        logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
+    if cfg.logit_softcap:
+        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+    return logits
+
+
 # ------------------------------------------------------- entry points ------
 
 
@@ -242,7 +255,9 @@ def loss_and_aux(params, cfg, batch, *, remat: bool = True,
     """Causal-LM loss.  batch: tokens/embeds (b,s[,d]), labels (b,s)."""
     h = embed_inputs(params, cfg, batch)
     h, _, aux = forward(params, cfg, h, remat=remat)
-    h = L.norm_apply(params["final_norm"], h, cfg.norm)
+    h = L.norm_apply(params["final_norm"], h, cfg.norm, cfg.norm_eps)
+    if cfg.logit_scale != 1.0:   # (h s) E^T = s (h E^T)
+        h = h * jnp.asarray(cfg.logit_scale, h.dtype)
     nll, cnt = chunked_cross_entropy(
         h, unembed_matrix(params, cfg), batch["labels"],
         chunk=loss_chunk, logit_softcap=cfg.logit_softcap,
@@ -257,13 +272,12 @@ def prefill(params, cfg, batch, *, cache_len: int | None = None):
     cache_len = cache_len or s
     caches = init_caches(cfg, b, cache_len)
     h, caches, _ = forward(params, cfg, h, caches=caches)
-    h_last = L.norm_apply(params["final_norm"], h[:, -1:], cfg.norm)
+    h_last = L.norm_apply(params["final_norm"], h[:, -1:], cfg.norm,
+                          cfg.norm_eps)
     logits = jnp.einsum("bsd,vd->bsv", h_last,
                         unembed_matrix(params, cfg).astype(h_last.dtype),
                         preferred_element_type=jnp.float32)
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return caches, logits, jnp.full((b,), s, jnp.int32)
+    return caches, final_logits(logits, cfg), jnp.full((b,), s, jnp.int32)
 
 
 def decode_step(params, cfg, caches, batch, pos):
@@ -274,10 +288,8 @@ def decode_step(params, cfg, caches, batch, pos):
     h = embed_inputs(params, cfg, batch, positions=positions)
     h, caches, _ = forward(params, cfg, h, positions=positions,
                            caches=caches, pos=pos)
-    h = L.norm_apply(params["final_norm"], h, cfg.norm)
+    h = L.norm_apply(params["final_norm"], h, cfg.norm, cfg.norm_eps)
     logits = jnp.einsum("bsd,vd->bsv", h,
                         unembed_matrix(params, cfg).astype(h.dtype),
                         preferred_element_type=jnp.float32)
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return logits, caches
+    return final_logits(logits, cfg), caches

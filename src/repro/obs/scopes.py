@@ -49,6 +49,11 @@ SCOPES = {
     "unpad": "the output sliced and reshaped to the caller's shape (a "
              "sparse layer's transpose back), and any post-hoc epilogue "
              "or row un-permutation",
+    "shard_vals": "a device-sharded product's per-call gather of each "
+                  "shard's values out of the global value vector",
+    "shard_concat": "a device-sharded product's output assembled from "
+                    "its shards: row blocks brought together (and the "
+                    "collective that does it), or column partials summed",
 }
 
 #: The ``name=`` of every ``pallas_call`` in ``repro.kernels``, and the

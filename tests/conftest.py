@@ -5,14 +5,15 @@ the host CPU into any number of devices — *if* the flag lands before jax
 initializes.  Two pieces make sharded tests runnable (not skipped)
 everywhere:
 
-* ``REPRO_FORCE_DEVICES=N``: honored here, at conftest import time —
-  before any test module imports jax — by appending
-  ``--xla_force_host_platform_device_count=N`` to ``XLA_FLAGS``.  This is
-  what ``make test-sharded`` sets.
+* ``REPRO_FORCE_DEVICES=N``: honored by the repository's root
+  ``conftest.py`` (four devices without it), at import time — before any
+  test module imports jax — through
+  ``--xla_force_host_platform_device_count=N`` in ``XLA_FLAGS``.  This
+  is what ``make test-sharded`` sets.
 * :func:`run_pytest_forced_devices`: runs a pytest target in a *fresh
   subprocess* with the env var set.  ``tests/test_distributed_spmm.py``
   uses it to wrap its device-hungry tests when the current process came
-  up with too few devices (the usual single-device ``pytest -q``), so the
+  up with too few devices (the usual four-device ``pytest -q``), so the
   tier-1 suite exercises the full 8-device matrix on any box.
 """
 from __future__ import annotations
@@ -20,14 +21,6 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
-
-_FORCE = os.environ.get("REPRO_FORCE_DEVICES")
-if _FORCE:
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            f"{_flags} --xla_force_host_platform_device_count="
-            f"{int(_FORCE)}").strip()
 
 import pytest
 
@@ -38,7 +31,7 @@ def run_pytest_forced_devices(target: str, n_devices: int,
 
     Returns the completed process (stdout/stderr captured, text mode).
     The child inherits the parent's interpreter and gets ``src`` on its
-    PYTHONPATH, ``REPRO_FORCE_DEVICES`` (picked up by this conftest
+    PYTHONPATH, ``REPRO_FORCE_DEVICES`` (picked up by the root conftest
     before jax initializes there), and a marker env var tests can use to
     avoid re-spawning recursively.
     """

@@ -27,7 +27,7 @@ def test_full_config_matches_assignment(arch):
     expected = {
         "olmoe-1b-7b": (16, 2048, 16, 16, 1024, 50304),
         "mixtral-8x22b": (56, 6144, 48, 8, 16384, 32768),
-        "command-r-35b": (40, 8192, 64, 8, 22528, 256000),
+        "command-r-35b": (40, 8192, 64, 64, 22528, 256000),
         "granite-3-2b": (40, 2048, 32, 8, 8192, 49155),
         "qwen2-72b": (80, 8192, 64, 8, 29568, 152064),
         "llama3.2-1b": (16, 2048, 32, 8, 8192, 128256),
